@@ -27,7 +27,11 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src"))
 
-from repro.launch.hostdev import ensure_host_devices  # noqa: E402
+from repro.launch.hostdev import (  # noqa: E402
+    enable_compile_cache,
+    ensure_host_devices,
+    require_devices,
+)
 
 N_WORKERS = 4
 ensure_host_devices(N_WORKERS)
@@ -407,4 +411,6 @@ if __name__ == "__main__":
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="BENCH_elastic_runner.json")
     args = ap.parse_args()
+    require_devices(N_WORKERS)
+    enable_compile_cache()
     run(steps=args.steps, seed=args.seed, out=args.out)

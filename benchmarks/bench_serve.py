@@ -39,7 +39,11 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src"))
 
-from repro.launch.hostdev import ensure_host_devices  # noqa: E402
+from repro.launch.hostdev import (  # noqa: E402
+    enable_compile_cache,
+    ensure_host_devices,
+    require_devices,
+)
 
 N_WORKERS = 4
 ensure_host_devices(N_WORKERS)
@@ -263,6 +267,8 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="tiny structural-assertion run for CI")
     args = ap.parse_args()
+    require_devices(N_WORKERS)
+    enable_compile_cache()
     if args.smoke:
         run_smoke(seed=args.seed)
     else:

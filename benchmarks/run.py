@@ -6,6 +6,10 @@ Prints ``name,us_per_call,derived`` CSV per line. Usage:
 
 ``--full`` uses the paper's exact sizes (5000 Monte-Carlo draws, 6000-dim
 power iteration); the default is a fast pass with identical semantics.
+
+The device benches run as child processes. This parent never imports jax
+(a parent holding the accelerator would starve its children), and any
+failed child makes the whole run exit non-zero.
 """
 
 import argparse
@@ -23,9 +27,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     if args.smoke:
-        _run_devices_subprocess("bench_engine.py", smoke=True, strict=True)
-        _run_devices_subprocess("bench_serve.py", smoke=True, strict=True)
-        _run_devices_subprocess("bench_faults.py", smoke=True, strict=True)
+        for script in ("bench_engine.py", "bench_serve.py", "bench_faults.py"):
+            if not _run_devices_subprocess(script, smoke=True):
+                raise SystemExit(1)
         print("# bench-smoke PASSED")
         return
 
@@ -51,31 +55,42 @@ def main(argv=None) -> None:
     bench_power_iteration.run(dim=6000 if args.full else 600)
     print("# --- extension: transition-waste-averse re-planning (ref [2] metric) ---")
     bench_transition_waste.run()
-    print("# --- live elastic runner: real execution under Markov churn ---")
-    _run_devices_subprocess("bench_elastic_runner.py",
-                            steps=24 if args.full else 12)
-    print("# --- ElasticEngine: steps/sec per workload x backend ---")
-    _run_devices_subprocess("bench_engine.py",
-                            steps=16 if args.full else 8)
-    print("# --- elastic serving: coalesced query traffic under churn ---")
-    _run_devices_subprocess("bench_serve.py",
-                            steps=48 if args.full else 24)
-    print("# --- fault recovery: detect->replan->re-execute, goodput vs fault rate ---")
-    _run_devices_subprocess("bench_faults.py",
-                            steps=8 if args.full else 4)
+    failed = []
+    for title, script, steps in (
+        ("live elastic runner: real execution under Markov churn",
+         "bench_elastic_runner.py", 24 if args.full else 12),
+        ("ElasticEngine: steps/sec per workload x backend",
+         "bench_engine.py", 16 if args.full else 8),
+        ("elastic serving: coalesced query traffic under churn",
+         "bench_serve.py", 48 if args.full else 24),
+        ("fault recovery: detect->replan->re-execute, goodput vs fault rate",
+         "bench_faults.py", 8 if args.full else 4),
+    ):
+        print(f"# --- {title} ---")
+        if not _run_devices_subprocess(script, steps=steps):
+            failed.append(script)
     print("# --- roofline (from the multi-pod dry-run artifacts) ---")
     roofline.run()
     print(f"# total {time.time() - t0:.1f}s")
+    if failed:
+        print(f"# FAILED: {', '.join(failed)}")
+        raise SystemExit(1)
 
 
-def _run_devices_subprocess(script: str, steps: int = 0, smoke: bool = False,
-                            strict: bool = False) -> None:
-    """Device benches need 4 forced host devices; jax pins the device count
-    at first init, so each gets its own interpreter (same trick as the
-    tests). ``strict`` propagates a failure as a non-zero exit (the
-    bench-smoke CI job's contract)."""
+def _run_devices_subprocess(script: str, steps: int = 0,
+                            smoke: bool = False) -> bool:
+    """Run one device bench in its own interpreter; True when it passed.
+
+    jax pins the device count at first init (the CPU benches force 4 host
+    devices), and an accelerator belongs to one process at a time — so
+    this parent must not have touched jax."""
     import os
     import subprocess
+
+    if "jax" in sys.modules:
+        raise RuntimeError(
+            f"benchmarks.run imported jax before starting {script}; the "
+            f"child could not reach the accelerator")
 
     bench_dir = os.path.dirname(os.path.abspath(__file__))
     env = dict(os.environ)
@@ -97,8 +112,7 @@ def _run_devices_subprocess(script: str, steps: int = 0, smoke: bool = False,
     if proc.returncode != 0:
         sys.stdout.write(f"# {script} FAILED (rc={proc.returncode})\n")
         sys.stdout.write(proc.stderr[-2000:] + "\n")
-        if strict:
-            raise SystemExit(proc.returncode)
+    return proc.returncode == 0
 
 
 if __name__ == "__main__":

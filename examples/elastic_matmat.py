@@ -22,7 +22,11 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src"))
 
-from repro.launch.hostdev import ensure_host_devices  # noqa: E402
+from repro.launch.hostdev import (  # noqa: E402
+    enable_compile_cache,
+    ensure_host_devices,
+    require_devices,
+)
 
 N_WORKERS = 4
 ensure_host_devices(N_WORKERS)
@@ -58,6 +62,8 @@ def main(argv=None):
     ap.add_argument("--steps", type=int, default=6)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    require_devices(N_WORKERS)
+    enable_compile_cache()
 
     x = make_exact_matrix(DIM, args.seed)
     rng = np.random.default_rng(args.seed + 1)

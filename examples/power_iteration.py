@@ -46,7 +46,11 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src"))
 
-from repro.launch.hostdev import ensure_host_devices  # noqa: E402
+from repro.launch.hostdev import (  # noqa: E402
+    enable_compile_cache,
+    ensure_host_devices,
+    require_devices,
+)
 
 N_WORKERS = 4
 ensure_host_devices(N_WORKERS)
@@ -96,13 +100,8 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    import jax
-
-    if jax.local_device_count() < N_WORKERS:
-        raise SystemExit(
-            f"need {N_WORKERS} devices, have {jax.local_device_count()} — "
-            "run without importing jax first (hostdev forces host devices)"
-        )
+    require_devices(N_WORKERS)
+    enable_compile_cache()
 
     x = make_exact_matrix(DIM, args.seed)
     true_eig = float(np.linalg.eigvalsh(x.astype(np.float64))[-1])

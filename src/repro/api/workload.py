@@ -315,9 +315,12 @@ class MatVecPowerIteration(MatVec):
         :func:`~repro.runtime.elastic_runner.quantize_unit` /
         :func:`~repro.runtime.elastic_runner.unit_vector` by construction —
         both sides square, tree-reduce, sqrt, divide and round with the same
-        explicit elementwise schedule in float32 (IEEE ops are exact given
-        the order, and the binary-tree reduction pins the order). This is
-        what makes a fused window's outputs bit-equal to K stepwise steps.
+        explicit schedule in float32 (the binary-tree reduction pins the
+        order; sqrt and divide are the integer-exact correctly rounded
+        routines of :func:`~repro.runtime.elastic_runner._normalize`,
+        because a TPU's own float32 sqrt and divide are not correctly
+        rounded). This is what makes a fused window's outputs bit-equal to
+        K stepwise steps on every backend.
 
         The per-step residual/eigenvalue *statistics* stay host-side: the
         engine replays :meth:`consume` on the window's (ys, ws) outputs and
@@ -333,11 +336,11 @@ class MatVecPowerIteration(MatVec):
         def upd(y, w):
             import jax.numpy as jnp
 
-            from repro.runtime.elastic_runner import _tree_sumsq
+            from repro.runtime.elastic_runner import _normalize
 
             del w
             v = y.astype(jnp.float32)
-            u = v / jnp.sqrt(_tree_sumsq(v, jnp))
+            u = _normalize(v, jnp)
             if not bits:
                 return u
             q = (jnp.round(u * (1 << bits)) /

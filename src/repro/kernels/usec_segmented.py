@@ -37,11 +37,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pragma: no cover - import surface differs off-TPU builds
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _segmented_kernel(slot_ref, off_ref, x_ref, w_ref, o_ref):
@@ -79,11 +75,6 @@ def usec_segmented_padded(
     Returns (B, block_rows, C) float32 — block i holds
     ``staged[slot[i], off[i]:off[i]+block_rows] @ w`` (fp32 accumulated).
     """
-    if pltpu is None:
-        raise RuntimeError(
-            "usec_segmented needs jax.experimental.pallas.tpu (scalar "
-            "prefetch) even in interpret mode; this jax build lacks it — "
-            "use mode='ref' (the gathered flat-matmul path) instead")
     t, rpt, k = staged.shape
     k2, c = w.shape
     if k != k2:

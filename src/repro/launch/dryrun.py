@@ -311,9 +311,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Optional[str])
     try:
         import contextlib
 
-        from repro.jax_compat import set_mesh as jc_set_mesh
-
-        ctx = jc_set_mesh(mesh_ctx) if mesh_ctx is not None else contextlib.nullcontext()
+        ctx = jax.set_mesh(mesh_ctx) if mesh_ctx is not None else contextlib.nullcontext()
         with ctx:
             lowered = fn.lower(*args)  # None args are valid empty pytrees
             t1 = time.time()
@@ -321,8 +319,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Optional[str])
         t2 = time.time()
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # jax 0.4.x: one dict per program
-            cost = cost[0] if cost else {}
         txt = compiled.as_text()
         from repro.launch import hlo_cost
         sc = hlo_cost.analyze(txt, default_trips=meta.get("avg_trips", 1.0))

@@ -1,7 +1,8 @@
 """CLI for the elastic serving layer: run a query trace, print metrics.
 
 Launches an :class:`~repro.serve.ElasticServer` over an exact integer
-demo matrix on forced host devices, pushes a seeded synthetic request
+demo matrix on four devices (four chips on a TPU host, forced host devices
+on the CPU), pushes a seeded synthetic request
 trace (matvec/matmat mix, Poisson-ish arrivals) through it — optionally
 with a mid-trace churn event — and prints the structured metrics
 snapshot (p50/p99 latency, goodput, queue/reject/deadline counters) as
@@ -18,7 +19,11 @@ from __future__ import annotations
 import argparse
 import json
 
-from repro.launch.hostdev import ensure_host_devices
+from repro.launch.hostdev import (
+    enable_compile_cache,
+    ensure_host_devices,
+    require_devices,
+)
 
 N_WORKERS = 4
 BASE_SPEEDS = (1000.0, 1400.0, 1900.0, 2600.0)
@@ -151,6 +156,8 @@ def main(argv=None):
                  f"got {args.corruption_rate}")
 
     ensure_host_devices(N_WORKERS)
+    require_devices(N_WORKERS)
+    enable_compile_cache()
     server, _ = build_server(args)
     responses = run_trace(server, args)
     snap = server.metrics_snapshot()

@@ -103,10 +103,8 @@ def main(argv=None):
     straggle = StragglerProcess(count=args.drop_stragglers, mode="uniform",
                                 seed=args.seed)
 
-    from repro.jax_compat import set_mesh as jc_set_mesh
-
     params = bundle.init(jax.random.PRNGKey(args.seed))
-    with jc_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         pshard = shr.param_shardings(
             jax.eval_shape(bundle.init, jax.random.PRNGKey(args.seed)), cfg, mesh
         )

@@ -153,7 +153,8 @@ def _moe_chunk(p: Dict, xt: jnp.ndarray, cfg) -> Tuple[jnp.ndarray, jnp.ndarray]
     combine = jnp.zeros((t, e, capacity), jnp.float32)
     for i in range(k):
         oh_e = onehot[:, i]                                               # (T, E)
-        oh_c = jax.nn.one_hot(pos[:, i], capacity, dtype=jnp.float32)     # (T, C)
+        oh_c = jax.nn.one_hot(pos[:, i].astype(jnp.int32), capacity,
+                              dtype=jnp.float32)                          # (T, C)
         d_i = (oh_e * keep[:, i: i + 1].astype(jnp.float32))[:, :, None] * oh_c[:, None, :]
         dispatch = dispatch + d_i
         combine = combine + d_i * gate_vals[:, i][:, None, None]

@@ -353,7 +353,6 @@ class ElasticRunner:
         policy=None,
     ):
         import jax
-        import jax.numpy as jnp
 
         from repro.launch.mesh import make_worker_mesh
 
@@ -362,6 +361,7 @@ class ElasticRunner:
             make_matvec_executor,
             make_worker_executor,
             stage_matrix,
+            worker_sharding,
         )
 
         if workload is None:
@@ -453,13 +453,14 @@ class ElasticRunner:
             out_cols=workload.out_cols,
             segmented_fn=seg_fn,
         )
-        # First-arrival mode dispatches per-worker partials instead of the
-        # monolithic psum step; ``widx`` is a traced scalar so ONE compiled
-        # program serves every worker (the jit-cache-of-1 invariant holds).
+        # First-arrival mode computes per-worker partials (no psum), each
+        # on its own worker's device; ONE compiled program serves every
+        # worker (the jit-cache-of-1 invariant holds).
         self._worker_exec = None
         if cfg.arrival == "first":
             self._worker_exec = make_worker_executor(
-                rows_total=q, block_rows=cfg.block_rows,
+                self.mesh, worker_axis, rows_total=q,
+                block_rows=cfg.block_rows,
                 matmul=workload.executor_fn(cfg.matmul_mode),
                 out_cols=workload.out_cols,
                 segmented_fn=seg_fn,
@@ -482,16 +483,19 @@ class ElasticRunner:
                     out_cols=workload.out_cols, update=upd,
                     segmented_fn=seg_fn,
                 )
-        self._staged_dev = jnp.asarray(self._staged.staged)
-        self._jnp = jnp
-        self._jax = jax
-        # The fused carry's placement: replicated over the worker mesh. The
-        # first window's host-provided operand is device_put with THIS
-        # sharding so it matches the carry the window returns — otherwise
-        # the second dispatch would recompile on the sharding change.
+        # Placement: worker n's staged tiles and plan rows live on worker
+        # n's device (the layout the shard_map in_specs ask for, so no
+        # dispatch reshards); operands and masks are replicated. The fused
+        # carry is replicated too, so a host-provided first operand matches
+        # the carry a window returns — otherwise the second dispatch would
+        # recompile on the sharding change.
         from jax.sharding import NamedSharding, PartitionSpec
 
+        self._jax = jax
+        self._by_worker = worker_sharding(self.mesh, worker_axis)
+        self._by_step_worker = worker_sharding(self.mesh, worker_axis, lead=1)
         self._replicated = NamedSharding(self.mesh, PartitionSpec())
+        self._staged_dev = self._put_workers(self._staged.staged)
 
         # With an explicit prior we trust its ratios; with the all-ones
         # default a never-measured machine carries no information, so it is
@@ -505,6 +509,7 @@ class ElasticRunner:
         self._plan_cache: "OrderedDict[Tuple[int, ...], _CacheEntry]" = OrderedDict()
         self._membership: Tuple[int, ...] = tuple(range(N))
         self._current: Optional[_CacheEntry] = None
+        self._operand_shape: Tuple[int, ...] = ()   # last dispatched operand
         self._pending_loads: Dict[int, float] = {}
         self._pending_durations: Dict[int, float] = {}
         self._step = 0
@@ -572,6 +577,14 @@ class ElasticRunner:
             "repaired_rows": 0,
             "graylist_events": 0,
         }
+
+    def _put_workers(self, a: np.ndarray):
+        """Place an array whose leading axis is the worker axis: worker
+        n's slice goes to worker n's device."""
+        return self._jax.device_put(a, self._by_worker)
+
+    def _put_replicated(self, a):
+        return self._jax.device_put(a, self._replicated)
 
     def integrity_snapshot(self) -> Dict[str, int]:
         """Integrity counters: runner-side recovery counts plus the
@@ -679,6 +692,33 @@ class ElasticRunner:
         return n
 
     @property
+    def staged_device(self):
+        """The staged tiles as placed on the devices: (N, T, rows_per_tile,
+        r), sharded so worker n's tiles live on worker n's device only."""
+        return self._staged_dev
+
+    def lowered_step_text(self) -> str:
+        """StableHLO of the step program this runner dispatches (the fused
+        window, the first-arrival partials or the barrier step), lowered
+        against the current plan's placed arrays without compiling — so a
+        caller can see which kernels and collectives a step runs. Needs
+        one executed step."""
+        if self._current is None:
+            raise RuntimeError("lowered_step_text needs one executed step")
+        w = self._put_replicated(np.zeros(self._operand_shape, np.float32))
+        if self._fused is not None and self._window_dev:
+            K, N = self.cfg.fuse_steps, self.placement.n_machines
+            stacks = next(reversed(self._window_dev.values()))[1]
+            fn, args = self._fused, (
+                self._staged_dev, *stacks,
+                self._put_replicated(np.zeros((K, N), bool)),
+                self._put_replicated(np.zeros((K,), bool)), w)
+        else:
+            fn = self._worker_exec or self._executor
+            args = (self._staged_dev, *self._current.dev, w)
+        return fn.lower(*args).as_text()
+
+    @property
     def executor_cache_size(self) -> int:
         """Compiled-program count across the step drivers (expected: 1
         forever — a fused run compiles only the window driver, a stepwise
@@ -731,18 +771,16 @@ class ElasticRunner:
         # Plan arrays live on device with the cache entry: a cache hit (or a
         # no-straggler step) uploads nothing, so the measured step wall time
         # is executor time, not host->device transfer.
-        jnp = self._jnp
+        put = self._put_workers
         dev = (
-            jnp.asarray(bp.blk_slot), jnp.asarray(bp.blk_off),
-            jnp.asarray(bp.blk_goff), jnp.asarray(bp.blk_include),
-            jnp.asarray(bp.n_blocks),
+            put(bp.blk_slot), put(bp.blk_off), put(bp.blk_goff),
+            put(bp.blk_include), put(bp.n_blocks),
         )
         entry = _CacheEntry(
             step_plan=splan, block=bp, include0=bp.blk_include.copy(),
             rows=rows, s_plan=s_plan, block_loads=block_loads, dev=dev,
             stragglers=int(splan.plan.stragglers),
-            dev_valid=jnp.asarray(
-                (bp.blk_seg_t >= 0).astype(np.float32)),
+            dev_valid=put((bp.blk_seg_t >= 0).astype(np.float32)),
         )
         # ---- commit point: nothing below can raise on a built entry ----
         self._plan_cache[avail] = entry
@@ -1177,7 +1215,7 @@ class ElasticRunner:
                 continue
             slot = int(self._staged.slot_of[n, int(stored[0])])
             corrupt_tile(self._staged.staged[n, slot])
-            self._staged_dev = self._jnp.asarray(self._staged.staged)
+            self._staged_dev = self._put_workers(self._staged.staged)
             self._live_tile_specs[n] = spec
 
     def _audit_and_restage(self, t: int) -> None:
@@ -1222,7 +1260,7 @@ class ElasticRunner:
                     f"staging fingerprint; re-staged from replica holder "
                     f"{donor} — capacity restored, plan untouched")
         if restaged:
-            self._staged_dev = self._jnp.asarray(self._staged.staged)
+            self._staged_dev = self._put_workers(self._staged.staged)
 
     def _graylist_forced(self, t: int, entry: _CacheEntry,
                          already: Set[int]) -> Set[int]:
@@ -1429,12 +1467,12 @@ class ElasticRunner:
                 detail=f"S={entry.stragglers} cannot cover corrupt "
                        f"worker(s) {list(lost)}")
         slot_d, off_d, goff_d, _inc0, nblk_d = entry.dev
-        include_d = self._jnp.asarray(refresh_include(
+        include_d = self._put_workers(refresh_include(
             entry.block, entry.step_plan.plan, tuple(sorted(bad_new))))
         y2 = self._executor(
             self._staged_dev,
             slot_d, off_d, goff_d, include_d, nblk_d,
-            self._jnp.asarray(w),
+            self._put_replicated(w),
         )
         y2.block_until_ready()
         self.device_dispatches += 1
@@ -1577,9 +1615,8 @@ class ElasticRunner:
         """
         from .executor import refresh_include
 
-        jnp = self._jnp
         t = self._step
-        slot_d, off_d, goff_d, _include0_d, nblk_d = entry.dev
+        slot_d, off_d, goff_d, _include0_d, _nblk_d = entry.dev
         valid_d = entry.dev_valid
         replan_s = time.perf_counter() - t0
 
@@ -1588,19 +1625,23 @@ class ElasticRunner:
             n for n in self._membership
             if entry.block.n_blocks[n] > 0 and n not in silent
         ]
-        w_dev = jnp.asarray(w)
+        # A worker that is not dispatched runs a zero trip count.
+        nblk = np.zeros_like(entry.block.n_blocks)
+        nblk[loaded] = entry.block.n_blocks[loaded]
+        self._operand_shape = np.shape(w)
         t1 = time.perf_counter()
-        parts_d = [
-            self._worker_exec(
-                self._staged_dev, np.int32(n), slot_d[n], off_d[n],
-                goff_d[n], valid_d[n], nblk_d[n], w_dev,
-            )
-            for n in loaded
-        ]
-        for p in parts_d:
-            p.block_until_ready()
+        parts_d = self._worker_exec(
+            self._staged_dev, slot_d, off_d, goff_d, valid_d,
+            self._put_workers(nblk), self._put_replicated(w),
+        )
+        # Each worker's partial is its own device's shard: fetch the
+        # loaded workers' shards one by one, never the whole array.
+        shard_of = {s.index[0].start or 0: s.data
+                    for s in parts_d.addressable_shards}
+        for n in loaded:
+            shard_of[n].block_until_ready()
         wall = time.perf_counter() - t1
-        self.device_dispatches += len(parts_d)
+        self.device_dispatches += 1
         self._last_step_wall = wall
 
         row_loads = entry.block_loads * self.rows_per_tile
@@ -1616,7 +1657,7 @@ class ElasticRunner:
             silent |= set(timed)
             for n in timed:
                 durations.pop(n, None)
-        parts = [np.asarray(p) for p in parts_d]
+        parts = [np.asarray(shard_of[n])[0] for n in loaded]
         silent, durations = self._integrity_first(
             t, entry, parts, loaded, w, silent, durations, injected)
         forced = tuple(sorted(silent))
@@ -1693,7 +1734,6 @@ class ElasticRunner:
         """
         from .executor import refresh_include
 
-        jnp = self._jnp
         if event is not None:
             self.apply_event(event)
         t = self._step
@@ -1735,15 +1775,17 @@ class ElasticRunner:
         slot_d, off_d, goff_d, include0_d, nblk_d = entry.dev
         include_d = (
             include0_d if not bad
-            else jnp.asarray(
+            else self._put_workers(
                 refresh_include(entry.block, entry.step_plan.plan, bad))
         )
         replan_s = time.perf_counter() - t0
 
+        self._operand_shape = np.shape(w)
         t1 = time.perf_counter()
+        w_dev = self._put_replicated(w)
         y = self._executor(
             self._staged_dev,
-            slot_d, off_d, goff_d, include_d, nblk_d, jnp.asarray(w),
+            slot_d, off_d, goff_d, include_d, nblk_d, w_dev,
         )
         y.block_until_ready()
         wall = time.perf_counter() - t1
@@ -1766,12 +1808,12 @@ class ElasticRunner:
             # re-dispatch with the refreshed include weights (same bits:
             # exactly one surviving copy of every segment delivers).
             bad = tuple(sorted(set(bad) | set(timed)))
-            include_d = jnp.asarray(
+            include_d = self._put_workers(
                 refresh_include(entry.block, entry.step_plan.plan, bad))
             t1b = time.perf_counter()
             y = self._executor(
                 self._staged_dev,
-                slot_d, off_d, goff_d, include_d, nblk_d, jnp.asarray(w),
+                slot_d, off_d, goff_d, include_d, nblk_d, w_dev,
             )
             y.block_until_ready()
             wall += time.perf_counter() - t1b
@@ -1922,7 +1964,6 @@ class ElasticRunner:
             raise RuntimeError(
                 "step_window needs fuse_steps > 1 and a fusable workload "
                 "(workload.fused_update returned None)")
-        jnp = self._jnp
         K = self.cfg.fuse_steps
         sets = [
             None if bad is None else tuple(sorted({int(s) for s in bad}))
@@ -2034,13 +2075,16 @@ class ElasticRunner:
         cached = self._window_dev.get(key)
         if cached is None:
             blocks = [e.block for e in entries]
-            stacks = (
-                jnp.asarray(np.stack([b.blk_slot for b in blocks])),
-                jnp.asarray(np.stack([b.blk_off for b in blocks])),
-                jnp.asarray(np.stack([b.blk_goff for b in blocks])),
-                jnp.asarray(np.stack([b.n_blocks for b in blocks])),
-                jnp.asarray(np.stack([b.blk_prio for b in blocks])),
-                jnp.asarray(np.stack([b.blk_seg_t >= 0 for b in blocks])),
+            stacks = tuple(
+                self._jax.device_put(np.stack(a), self._by_step_worker)
+                for a in (
+                    [b.blk_slot for b in blocks],
+                    [b.blk_off for b in blocks],
+                    [b.blk_goff for b in blocks],
+                    [b.n_blocks for b in blocks],
+                    [b.blk_prio for b in blocks],
+                    [b.blk_seg_t >= 0 for b in blocks],
+                )
             )
             cached = (entries, stacks)
             self._window_dev[key] = cached
@@ -2053,12 +2097,12 @@ class ElasticRunner:
 
         t1 = time.perf_counter()
         w_dev = (
-            w if hasattr(w, "block_until_ready")
-            else self._jax.device_put(w, self._replicated)
+            w if hasattr(w, "block_until_ready") else self._put_replicated(w)
         )
+        self._operand_shape = tuple(w_dev.shape)
         w_carry, ys_d, ws_d = self._fused(
             self._staged_dev, *cached[1],
-            jnp.asarray(bad), jnp.asarray(active), w_dev,
+            self._put_replicated(bad), self._put_replicated(active), w_dev,
         )
         self.device_dispatches += 1
         # Overlap: the dispatch above is asynchronous — spend the device
@@ -2195,6 +2239,99 @@ def _tree_sumsq(v, xp):
     return s[0]
 
 
+def _bits(x, xp):
+    """float32 -> int32 bit pattern (numpy or jax)."""
+    if xp is np:
+        return np.asarray(x, np.float32).view(np.int32)
+    import jax
+
+    return jax.lax.bitcast_convert_type(x.astype(xp.float32), xp.int32)
+
+
+def _float(b, xp):
+    """int32 bit pattern -> float32 (numpy or jax)."""
+    if xp is np:
+        return np.asarray(b, np.int32).view(np.float32)
+    import jax
+
+    return jax.lax.bitcast_convert_type(b, xp.float32)
+
+
+def _rn_sqrt(s, xp):
+    """Correctly rounded float32 ``sqrt`` of a positive normal scalar, by
+    integer digit-by-digit square root.
+
+    A TPU's float32 ``sqrt`` and divide are not correctly rounded (they
+    refine a hardware estimate), so a device normalize built on them
+    disagrees with NumPy in the last ulp. This routine and
+    :func:`_rn_div` use only int32 shifts, adds and compares — exact on
+    every backend — and return the IEEE round-to-nearest-even result, the
+    same bits as ``np.sqrt``."""
+    b = _bits(s, xp)
+    e = (b >> 23) & 0xFF
+    m = (b & 0x7FFFFF) | 0x800000               # s = m * 2^(e - 150)
+    odd = (e & 1) == 1                          # e - 150 odd
+    m = xp.where(odd, m << 1, m << 2)           # m in [2^24, 2^26)
+    f = xp.where(odd, e - 151, e - 152)         # s = m * 2^f, f even
+    # root = isqrt(m * 2^24), 25 bits: 24 significant + 1 guard.
+    root = xp.zeros_like(m)
+    rem = xp.zeros_like(m)
+    for i in range(25):
+        pair = (m >> (24 - 2 * i)) & 3 if i < 13 else 0
+        rem = (rem << 2) | pair
+        trial = (root << 2) | 1
+        take = rem >= trial
+        rem = xp.where(take, rem - trial, rem)
+        root = (root << 1) | take.astype(root.dtype)
+    mant = root >> 1
+    up = ((root & 1) == 1) & ((rem != 0) | ((mant & 1) == 1))
+    mant = mant + up.astype(mant.dtype)
+    exp = 139 + (f >> 1)
+    carry = mant >> 24                          # rounded up to 2^24
+    mant, exp = mant >> carry, exp + carry
+    return _float((exp << 23) | (mant & 0x7FFFFF), xp)
+
+
+def _rn_div(a, d, xp):
+    """Correctly rounded float32 ``a / d`` for normal or zero ``a`` and a
+    positive normal scalar ``d``, by integer long division (see
+    :func:`_rn_sqrt` for why). Same bits as NumPy's ``a / d`` whenever the
+    quotient is normal."""
+    ba, bd = _bits(a, xp), _bits(d, xp)
+    ea, ed = (ba >> 23) & 0xFF, (bd >> 23) & 0xFF
+    ma = (ba & 0x7FFFFF) | 0x800000
+    md = (bd & 0x7FFFFF) | 0x800000
+    # 26 quotient bits of ma / md: 1 integer bit + 25 fraction bits.
+    take = ma >= md
+    q = take.astype(ma.dtype)
+    r = xp.where(take, ma - md, ma)
+    for _ in range(25):
+        r = r << 1
+        take = r >= md
+        q = (q << 1) | take.astype(q.dtype)
+        r = xp.where(take, r - md, r)
+    big = q >= (1 << 25)                        # quotient in [1, 2)
+    mant = xp.where(big, q >> 2, q >> 1)
+    guard = xp.where(big, (q >> 1) & 1, q & 1)
+    sticky = (r != 0) | (big & ((q & 1) == 1))
+    up = (guard == 1) & (sticky | ((mant & 1) == 1))
+    mant = mant + up.astype(mant.dtype)
+    exp = ea - ed + xp.where(big, 127, 126)
+    carry = mant >> 24
+    mant, exp = mant >> carry, exp + carry
+    out = (ba & (-0x7FFFFFFF - 1)) | (exp << 23) | (mant & 0x7FFFFF)
+    return xp.where((ba & 0x7FFFFFFF) == 0, _float(ba, xp), _float(out, xp))
+
+
+def _normalize(v, xp):
+    """``v / ||v||`` in float32 with a pinned schedule: the
+    :func:`_tree_sumsq` norm, then correctly rounded sqrt and divide
+    (:func:`_rn_sqrt`, :func:`_rn_div`). Every op is exact given its
+    inputs on every backend, so NumPy and a TPU produce the SAME bits —
+    the fused window's device update is bitwise-equal to the host's."""
+    return _rn_div(v, _rn_sqrt(_tree_sumsq(v, xp), xp), xp)
+
+
 def make_exact_matrix(
     dim: int, seed: int = 0, lo: int = -3, hi: int = 3, diag: int = 40
 ) -> np.ndarray:
@@ -2221,8 +2358,8 @@ def quantize_unit(v: np.ndarray, bits: int = 8) -> np.ndarray:
     reference regardless of block order, and the runner's ``verify="exact"``
     mode holds at every step.
 
-    The math is float32 with a :func:`_tree_sumsq` norm: a fully explicit
-    elementwise schedule that jax reproduces bit for bit, so the fused
+    The math is float32 with a :func:`_normalize` norm: a fully explicit
+    schedule that jax reproduces bit for bit on every backend, so the fused
     device driver can run the SAME update in-graph
     (:meth:`~repro.api.workload.MatVecPowerIteration.fused_update`) and a
     K-step window stays bitwise-equal to K stepwise host updates. (Snapping
@@ -2230,7 +2367,7 @@ def quantize_unit(v: np.ndarray, bits: int = 8) -> np.ndarray:
     immaterial; the grid exactness argument above is unchanged.)
     """
     v = np.asarray(v, dtype=np.float32)
-    u = v / np.sqrt(_tree_sumsq(v, np))
+    u = _normalize(v, np)
     q = (np.round(u * (1 << bits)) / np.float32(1 << bits)).astype(np.float32)
     if not np.any(q):
         q = np.zeros_like(u)
@@ -2239,10 +2376,10 @@ def quantize_unit(v: np.ndarray, bits: int = 8) -> np.ndarray:
 
 
 def unit_vector(v: np.ndarray) -> np.ndarray:
-    """Float32 normalize with the :func:`_tree_sumsq` schedule — the
+    """Float32 normalize with the :func:`_normalize` schedule — the
     unquantized iterate update, bitwise-reproducible on device."""
     v = np.asarray(v, dtype=np.float32)
-    return v / np.sqrt(_tree_sumsq(v, np))
+    return _normalize(v, np)
 
 
 @dataclass

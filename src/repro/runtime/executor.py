@@ -27,14 +27,20 @@ the same compiled computation, bit for bit):
   from a per-step straggler bitmask (:func:`device_include_weights`, the
   device-side twin of :func:`refresh_include`), and the iterate carry is
   donated — so a window costs ONE host round-trip for K steps;
-- :func:`make_worker_executor` — the first-arrival variant: ONE jitted
-  program computing a *single worker's* unmasked partial, dispatched once
-  per available worker. Each dispatch is independently fetchable, so the
-  master can consume completions in arrival order (the paper's "first
-  N_t − S results" semantics) instead of blocking on the collective psum
-  barrier; the combine weights are applied host-side *after* the realized
-  straggler set is known (:meth:`ElasticRunner.step` with
+- :func:`make_worker_executor` — the first-arrival variant: the same
+  per-worker body WITHOUT the psum. Every worker's unmasked partial stays
+  on the device that holds its staged shard (output sharded over the
+  worker axis, no collective), and each shard is independently fetchable,
+  so the master can consume completions in arrival order (the paper's
+  "first N_t − S results" semantics) instead of blocking on the collective
+  psum barrier; the combine weights are applied host-side *after* the
+  realized straggler set is known (:meth:`ElasticRunner.step` with
   ``arrival="first"``).
+
+Every array a step reads is placed once, where the program expects it:
+staged tiles and per-worker plan rows sharded over the worker axis
+(:func:`worker_sharding`), the operand replicated — so no dispatch moves
+a worker's data to another device.
 """
 
 from __future__ import annotations
@@ -43,10 +49,9 @@ from dataclasses import dataclass
 from typing import Callable, Optional, Sequence, Tuple
 
 import jax
-
-from repro.jax_compat import shard_map
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.plan import CompiledPlan
@@ -343,6 +348,17 @@ def _default_matmul(xb, wb):
     )
 
 
+def worker_sharding(mesh: jax.sharding.Mesh, worker_axis: str,
+                    lead: int = 0) -> NamedSharding:
+    """Sharding of an array whose axis ``lead`` is the worker axis: worker
+    n's slice lives on worker n's device (the staged tiles, the (N, B)
+    plan rows, and — with ``lead=1`` — the fused window's (K, N, B) plan
+    stacks). Placing inputs this way is what keeps every dispatch free of
+    resharding: it is exactly the layout the shard_map ``in_specs`` ask
+    for."""
+    return NamedSharding(mesh, P(*([None] * lead), worker_axis))
+
+
 def _make_worker_body(
     worker_axis: str,
     rows_total: int,
@@ -350,9 +366,10 @@ def _make_worker_body(
     mm: Callable,
     out_cols: Optional[int],
     segmented_fn: Optional[Callable],
+    combine: bool = True,
 ):
-    """The per-worker, per-step computation shared by the stepwise and fused
-    executors — ONE definition so the two drivers are the same compiled math.
+    """The per-worker, per-step computation shared by all three executors —
+    ONE definition so the drivers are the same compiled math.
 
     With ``segmented_fn`` the per-block ``fori_loop`` is replaced by one
     whole-block-list call (the segment-aware kernel path): ``segmented_fn``
@@ -360,6 +377,11 @@ def _make_worker_body(
     scatter-added into the output rows. Per-worker output rows are disjoint
     (each worker computes an assigned row once), so add equals the loop's
     overwrite; padding blocks carry include == 0 and add exact zeros.
+
+    ``combine`` psums the partials over the worker axis (the barrier
+    master); without it each worker returns its own partial with a leading
+    worker axis of 1, for an output sharded over the workers (the
+    first-arrival master fetches the shards one by one).
     """
 
     def body(staged, blk_slot, blk_off, blk_goff, blk_include, n_blocks, w):
@@ -401,23 +423,25 @@ def _make_worker_body(
                 return jax.lax.dynamic_update_slice(y, yb, (blk_goff[i], 0))
 
             y = jax.lax.fori_loop(0, n_blocks[0], step, y0)
-        y = jax.lax.psum(y, worker_axis)
+        if combine:
+            y = jax.lax.psum(y, worker_axis)
         # A 1-d operand squeezes back to a vector only when the output width
         # follows the operand; an explicit out_cols keeps its matrix shape.
-        return y if (w.ndim == 2 or out_cols is not None) else y[:, 0]
+        y = y if (w.ndim == 2 or out_cols is not None) else y[:, 0]
+        return y if combine else y[None]
 
     return body
 
 
-def _shard(body, mesh, worker_axis):
-    return shard_map(
+def _shard(body, mesh, worker_axis, combine: bool = True):
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
             P(worker_axis), P(worker_axis), P(worker_axis), P(worker_axis),
             P(worker_axis), P(worker_axis), P(),
         ),
-        out_specs=P(),
+        out_specs=P() if combine else P(worker_axis),
         axis_names={worker_axis},
         check_vma=False,
     )
@@ -459,72 +483,40 @@ def make_matvec_executor(
 
 
 def make_worker_executor(
+    mesh: jax.sharding.Mesh,
+    worker_axis: str,
     rows_total: int,
     block_rows: int,
     matmul: Optional[Callable] = None,
     out_cols: Optional[int] = None,
     segmented_fn: Optional[Callable] = None,
 ) -> Callable:
-    """Build the jitted per-worker partial for first-arrival execution.
+    """Build the jitted per-worker partials for first-arrival execution.
 
-    Returns ``partial(staged, widx, blk_slot, blk_off, blk_goff,
-    blk_include, n_blocks, w) -> y_n`` where ``staged`` is the full
-    (N, T, rows_per_tile, r) staged matrix, ``widx`` the worker id (a
-    traced scalar — one compiled program serves every worker, so the jit
-    cache stays at 1), and the ``blk_*`` rows are that worker's (B,) plan
-    slices. The output is worker ``widx``'s **unmasked** (rows_total,
-    [c]) partial: every real block contributes with weight 1 (callers pass
-    the valid-block mask as ``blk_include``), because the realized
-    straggler set is not known at dispatch time — first-arrival masking is
-    the master's business, applied host-side per row once arrivals decide
-    the winners (:func:`refresh_include` + a winner gather).
+    Returns ``partials(staged, blk_slot, blk_off, blk_goff, blk_include,
+    n_blocks, w) -> ys`` with the same arguments as
+    :func:`make_matvec_executor`; ``ys`` is (N, rows_total[, c]), sharded
+    over the worker axis: row n is worker n's **unmasked** partial,
+    computed on the device that holds worker n's staged tiles. Callers
+    pass the valid-block mask as ``blk_include`` (every real block
+    contributes with weight 1), because the realized straggler set is not
+    known at dispatch time — first-arrival masking is the master's
+    business, applied host-side per row once arrivals decide the winners
+    (:func:`refresh_include` + a winner gather). A worker that is not
+    dispatched gets a zero trip count.
 
-    Unlike the monolithic executors there is no mesh and no collective:
-    each worker's dispatch is an independent device call the master can
-    fetch in completion order. The per-block math (``dynamic_slice`` →
-    ``matmul`` → ``dynamic_update_slice``, or the segmented whole-list
-    path) is the same schedule as :func:`_make_worker_body`, so a
-    first-arrival combine of the winners' rows is bitwise-equal to the
-    barrier psum on the same plan.
+    There is no collective: each worker's shard completes on its own
+    device and is fetched independently, so the master can consume
+    partials in completion order. The per-worker math is
+    :func:`_make_worker_body` itself, so a first-arrival combine of the
+    winners' rows is bitwise-equal to the barrier psum on the same plan.
+    One compiled program serves every worker (the jit cache stays at 1).
     """
-    mm = matmul or _default_matmul
-
-    def partial_fn(staged, widx, blk_slot, blk_off, blk_goff,
-                   blk_include, n_blocks, w):
-        st = staged[widx]                       # (T, rows_per_tile, r)
-        w2 = w if w.ndim == 2 else w[:, None]
-        cols = w2.shape[1] if out_cols is None else out_cols
-
-        if segmented_fn is not None:
-            def _compute():
-                compact = segmented_fn(st, blk_slot, blk_off,
-                                       blk_include, w2)
-                rows = (
-                    blk_goff[:, None]
-                    + jnp.arange(block_rows, dtype=jnp.int32)
-                ).reshape(-1)
-                return jnp.zeros((rows_total, cols), jnp.float32) \
-                    .at[rows].add(compact.reshape(-1, cols))
-
-            y = jax.lax.cond(
-                n_blocks > 0, _compute,
-                lambda: jnp.zeros((rows_total, cols), jnp.float32))
-        else:
-            y0 = jnp.zeros((rows_total, cols), jnp.float32)
-
-            def step(i, y):
-                xb = jax.lax.dynamic_slice(
-                    st[blk_slot[i]],
-                    (blk_off[i], 0),
-                    (block_rows, st.shape[-1]),
-                )
-                yb = mm(xb, w2) * blk_include[i]
-                return jax.lax.dynamic_update_slice(y, yb, (blk_goff[i], 0))
-
-            y = jax.lax.fori_loop(0, n_blocks, step, y0)
-        return y if (w.ndim == 2 or out_cols is not None) else y[:, 0]
-
-    return jax.jit(partial_fn)
+    body = _make_worker_body(
+        worker_axis, rows_total, block_rows, matmul or _default_matmul,
+        out_cols, segmented_fn, combine=False,
+    )
+    return jax.jit(_shard(body, mesh, worker_axis, combine=False))
 
 
 def make_fused_executor(

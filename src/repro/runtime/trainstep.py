@@ -27,7 +27,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 
-from repro.jax_compat import shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -133,7 +132,7 @@ def make_usec_train_step(
         grads = jax.lax.psum(grads, axis)
         return grads, nll, ntok
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         manual_body,
         mesh=mesh,
         in_specs=(P(dp), P(dp), P(dp), P(dp), P()),
@@ -143,7 +142,7 @@ def make_usec_train_step(
     )
 
     if compress_grads:
-        compress_map = shard_map(
+        compress_map = jax.shard_map(
             lambda g, st: compression.compress_decompress(
                 g, st, dp if len(dp) > 1 else dp[0]
             ),

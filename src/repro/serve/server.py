@@ -600,6 +600,12 @@ class ElasticServer:
     def queue_depth(self) -> int:
         return len(self._queue)
 
+    @property
+    def lanes(self) -> Dict[str, ElasticEngine]:
+        """The per-kind engines (``"linear"``, and ``"mapreduce"`` when
+        open) — a copy of the mapping, for inspection."""
+        return dict(self._lanes)
+
     def metrics_snapshot(self) -> Dict:
         """The metrics dict plus live per-lane dispatch-layer state
         (executor compile counts — the jit-cache-of-1 assertion — and

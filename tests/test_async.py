@@ -150,7 +150,7 @@ yf, rf, runner_f = run_steps("first", 0)
 # and the per-worker winner-gather must reproduce the psum barrier bitwise.
 assert all((a == b).all() for a, b in zip(yb, yf))
 assert all(r.straggled == () for r in rf)
-# one compiled program serves every worker (widx is traced data)
+# one compiled program computes every worker's partial
 assert runner_f.executor_cache_size == 1, runner_f.executor_cache_size
 # per-step completion identical too: nothing is skipped
 mb = [r.modeled_completion for r in rb]

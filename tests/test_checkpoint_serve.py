@@ -218,11 +218,14 @@ def test_checkpoint_roundtrip_property(outer, inner, on_device, step, seed):
         assert h_np.tobytes() == w_np.tobytes()
 
 
+_RESUME_CHECKED: set = set()
+
+
 @given(
     fuse=st.sampled_from((1, 4)),
     cut=st.sampled_from((3, 5)),
 )
-def test_engine_save_state_resume_bitwise_property(fuse, cut, _cache={}):
+def test_engine_save_state_resume_bitwise_property(fuse, cut):
     """The full-engine drill as a property over cut points: run `cut`
     steps, snapshot the COMPLETE resumable state (iterate, EWMA, plan
     cache keys, clock RNG, pending measurements), resume in a FRESH
@@ -231,9 +234,9 @@ def test_engine_save_state_resume_bitwise_property(fuse, cut, _cache={}):
     every (fuse, cut) pair is a mid-trace cut for the EWMA/plan state.
     Each example is a subprocess; the tiny domain keeps this tractable
     under both real hypothesis and the fallback sampler."""
-    if (fuse, cut) in _cache:
+    if (fuse, cut) in _RESUME_CHECKED:
         return
-    _cache[(fuse, cut)] = True
+    _RESUME_CHECKED.add((fuse, cut))
     out = run_with_devices("""
 import numpy as np
 from repro.api import ElasticEngine, EngineConfig, MatVecPowerIteration, Policy

@@ -21,12 +21,12 @@ rng = np.random.default_rng(0)
 X = rng.normal(size=(G*64, 48)).astype(np.float32)
 w = rng.normal(size=(48,)).astype(np.float32)
 st = stage_matrix(X, p, 64)
-from repro.jax_compat import make_mesh, set_mesh
-mesh = make_mesh((6,), ("data",), devices=jax.devices()[:6])
+mesh = jax.make_mesh((6,), ("data",), devices=jax.devices()[:6],
+                     axis_types=(jax.sharding.AxisType.Auto,))
 ex = make_matvec_executor(mesh, "data", rows_total=G*64, block_rows=16)
 for bad in [(), (5,), (0,), (3,)]:
     bp = block_plan(plan, st.slot_of, 16, stragglers=bad)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y = ex(jnp.asarray(st.staged), jnp.asarray(bp.blk_slot), jnp.asarray(bp.blk_off),
                jnp.asarray(bp.blk_goff), jnp.asarray(bp.blk_include), jnp.asarray(bp.n_blocks), jnp.asarray(w))
     err = float(np.max(np.abs(np.asarray(y) - X @ w)))
@@ -48,7 +48,6 @@ from repro.data import TokenPipeline
 from repro.runtime.trainstep import make_usec_train_step, make_fsdp_train_step
 from repro.runtime.executor import block_plan
 from repro.launch.mesh import make_worker_mesh
-from repro.jax_compat import set_mesh
 from repro.optim import adamw
 
 cfg = ArchConfig(name="t", family="dense", n_layers=2, d_model=32, n_heads=2, n_kv_heads=2,
@@ -64,7 +63,7 @@ sb = pipe.staged_for_step(0)
 bp = block_plan(plan, sb.slot_of, 1)
 params = bundle.init(jax.random.PRNGKey(0))
 copy = lambda t: jax.tree.map(jnp.array, t)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     opt = adamw.init(params)
     ustep = make_usec_train_step(bundle, mesh, sb.arrays["tokens"].shape[1], bp.b_max)
     _, _, _, m1 = ustep(copy(params), copy(opt), None,
@@ -94,7 +93,6 @@ from repro.data import TokenPipeline
 from repro.runtime.trainstep import make_usec_train_step
 from repro.runtime.executor import block_plan
 from repro.launch.mesh import make_worker_mesh
-from repro.jax_compat import set_mesh
 from repro.optim import adamw
 
 cfg = ArchConfig(name="t", family="dense", n_layers=1, d_model=32, n_heads=2, n_kv_heads=2,
@@ -111,7 +109,7 @@ sb = pipe.staged_for_step(0)
 params = bundle.init(jax.random.PRNGKey(0))
 losses = []
 copy = lambda t: jax.tree.map(jnp.array, t)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     opt = adamw.init(params)
     step = make_usec_train_step(bundle, mesh, sb.arrays["tokens"].shape[1],
                                 int(plan.n_valid.max()) + 1)
@@ -136,20 +134,19 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.runtime import compression
 
-from repro.jax_compat import make_mesh, set_mesh, shard_map
-mesh = make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 params = {"w": jnp.zeros((8, 8))}
 state = compression.init_state(params)
 
 def reduce_fn(g, st):
     return compression.compress_decompress(g, st, "data")
 
-f = shard_map(reduce_fn, mesh=mesh, in_specs=(P("data"), P()),
+f = jax.shard_map(reduce_fn, mesh=mesh, in_specs=(P("data"), P()),
                   out_specs=(P(), P()), axis_names={"data"}, check_vma=False)
 rng = np.random.default_rng(0)
 g_global = rng.normal(size=(4, 8, 8)).astype(np.float32) * 0.01
 want = g_global.sum(0)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     total_err = []
     st = state
     for it in range(8):
@@ -185,13 +182,13 @@ import jax, jax.numpy as jnp, numpy as np, tempfile, os
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.runtime import checkpoint as ckpt
 
-from repro.jax_compat import make_mesh
-mesh4 = make_mesh((4,), ("data",))
+mesh4 = jax.make_mesh((4,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 x = jax.device_put(jnp.arange(64.0).reshape(8, 8),
                    NamedSharding(mesh4, P("data", None)))
 d = tempfile.mkdtemp()
 ckpt.save_checkpoint(d, 3, {"x": x})
-mesh2 = make_mesh((2,), ("data",), devices=jax.devices()[:2])
+mesh2 = jax.make_mesh((2,), ("data",), devices=jax.devices()[:2],
+                      axis_types=(jax.sharding.AxisType.Auto,))
 step, tree, _ = ckpt.restore_checkpoint(
     ckpt.latest_checkpoint(d), {"x": jnp.zeros((8, 8))},
     shardings={"x": NamedSharding(mesh2, P("data", None))})

@@ -359,3 +359,48 @@ assert np.array_equal(y2, y0)
 print("STALE-S-OK")
 """, n_devices=4)
     assert "STALE-S-OK" in out
+
+
+@pytest.mark.parametrize("arrival", ["barrier", "first"])
+def test_worker_data_lives_on_its_own_device(arrival):
+    """Staged tiles and plan rows are placed by worker (worker n's slice on
+    worker n's device only), the step program expects exactly that layout
+    (no resharding on dispatch), the barrier step psums while the
+    first-arrival partials stay on their own devices."""
+    out = run_with_devices("""
+import numpy as np
+from repro.api import ElasticEngine, EngineConfig, MatVecPowerIteration, Policy
+from repro.runtime import make_exact_matrix
+
+eng = ElasticEngine(
+    MatVecPowerIteration(seed=0),
+    Policy(placement="cyclic", replication=3, stragglers=1),
+    EngineConfig(block_rows=16, verify="exact", arrival=%r),
+    backend="device", n_machines=4)
+eng.run(make_exact_matrix(4 * 96, 0), n_steps=2)
+r = eng.runner
+devs = list(r.mesh.devices.flat)
+staged = r.staged_device
+assert staged.shape == (4, 3, 96, 4 * 96), staged.shape
+shards = sorted(staged.addressable_shards, key=lambda s: s.index[0].start)
+assert [s.device for s in shards] == devs
+assert all(s.data.shape == (1, 3, 96, 4 * 96) for s in shards)
+assert all(s.index[0] == slice(n, n + 1) for n, s in enumerate(shards))
+for a in r._current.dev:
+    assert a.sharding.is_equivalent_to(r._by_worker, a.ndim), a.sharding
+fn = r._worker_exec or r._executor
+w = r._put_replicated(np.zeros(4 * 96, np.float32))
+args = (staged, *r._current.dev, w)
+compiled = fn.lower(*args).compile()
+for a, want in zip(args, compiled.input_shardings[0]):
+    assert a.sharding.is_equivalent_to(want, a.ndim), (a.sharding, want)
+text = r.lowered_step_text()
+assert ("all_reduce" in text) == (%r == "barrier"), text[:400]
+if %r == "first":
+    ys = fn(*args)
+    assert ys.shape == (4, 4 * 96)
+    assert [s.device for s in sorted(ys.addressable_shards,
+            key=lambda s: s.index[0].start)] == devs
+print("PLACED-OK")
+""" % (arrival, arrival, arrival), n_devices=4)
+    assert "PLACED-OK" in out

@@ -284,3 +284,42 @@ assert np.array_equal(mm_seg.result, mm_base.result)
 print("SEGMENTED-PARITY-OK")
 """, n_devices=4)
     assert "SEGMENTED-PARITY-OK" in out
+
+
+def test_normalize_is_correctly_rounded_on_numpy_and_jax():
+    """The iterate normalize's sqrt and divide are built from int32 ops
+    and return IEEE round-to-nearest-even bits — NumPy's — on every
+    backend. (A TPU's own float32 sqrt and divide are approximate, which
+    would break the fused window's bitwise parity with the host.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.runtime.elastic_runner import (
+        _normalize,
+        _rn_div,
+        _rn_sqrt,
+        _tree_sumsq,
+    )
+
+    rng = np.random.default_rng(0)
+    n = 1 << 18
+    a = ((rng.random(n) - 0.5) * 2e4).astype(np.float32)
+    a[:4] = (0.0, -0.0, 1.0, -1.0)
+    s = (rng.random(n) * 1e6 + 1e-3).astype(np.float32)
+    squares = np.float32(rng.integers(1, 4096, size=1024)) ** 2
+    div_jax = jax.jit(lambda a, d: _rn_div(a, d, jnp))
+    sqrt_jax = jax.jit(lambda s: _rn_sqrt(s, jnp))
+    for d in (np.float32(1.0), np.float32(3.0), np.float32(123.456),
+              np.float32(1e-20), np.float32(7e20)):
+        want = (a / d).view(np.int32)
+        assert np.array_equal(_rn_div(a, d, np).view(np.int32), want)
+        assert np.array_equal(np.asarray(div_jax(a, d)).view(np.int32),
+                              want)
+    for v in (s, squares):
+        assert np.array_equal(_rn_sqrt(v, np), np.sqrt(v))
+        assert np.array_equal(np.asarray(sqrt_jax(v)), np.sqrt(v))
+    v = rng.normal(size=24576).astype(np.float32)
+    want = v / np.sqrt(_tree_sumsq(v, np))
+    assert np.array_equal(_normalize(v, np), want)
+    assert np.array_equal(
+        np.asarray(jax.jit(lambda v: _normalize(v, jnp))(v)), want)
