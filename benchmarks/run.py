@@ -1,4 +1,4 @@
-"""Benchmark harness — one section per paper table/figure + roofline.
+"""Benchmark harness — one section per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV per line. Usage:
 
@@ -39,7 +39,6 @@ def main(argv=None) -> None:
         bench_power_iteration,
         bench_straggler_tradeoff,
         bench_transition_waste,
-        roofline,
     )
 
     t0 = time.time()
@@ -69,8 +68,6 @@ def main(argv=None) -> None:
         print(f"# --- {title} ---")
         if not _run_devices_subprocess(script, steps=steps):
             failed.append(script)
-    print("# --- roofline (from the multi-pod dry-run artifacts) ---")
-    roofline.run()
     print(f"# total {time.time() - t0:.1f}s")
     if failed:
         print(f"# FAILED: {', '.join(failed)}")

@@ -577,9 +577,15 @@ class ElasticEngine:
         if self.backend == "device":
             if n_steps is None:
                 raise ValueError("the device backend needs an explicit n_steps")
-            return self._run_device(data, int(n_steps), events,
-                                    straggler_sets, operand,
-                                    kill_scheduler_at, faults)
+            from jax.profiler import TraceAnnotation
+
+            # Under a profiler trace the call is the span ``usec.run``:
+            # its self time is the engine's own set-up, loop and finalize,
+            # outside the runner's steps and the workload's consume.
+            with TraceAnnotation("usec.run", steps=n_steps):
+                return self._run_device(data, int(n_steps), events,
+                                        straggler_sets, operand,
+                                        kill_scheduler_at, faults)
         if kill_scheduler_at is not None:
             raise ValueError(
                 "kill_scheduler_at is a device-backend fault injection; "
@@ -638,6 +644,8 @@ class ElasticEngine:
     def _run_device(self, data, n_steps, events, straggler_sets,
                     operand, kill_scheduler_at=None,
                     faults=None) -> EngineResult:
+        from jax.profiler import TraceAnnotation
+
         from repro.faults.chaos import FaultAbort, FaultInjector, FaultSpec
 
         if self._runner is None:
@@ -861,8 +869,9 @@ class ElasticEngine:
                 # stepwise; consume's returned operand is discarded — the
                 # device already carried the (bitwise-identical) iterate.
                 for k in range(len(sets)):
-                    last = wl.combine(ys[k])
-                    wl.consume(last, ws[k])
+                    with TraceAnnotation("usec.consume"):
+                        last = wl.combine(ys[k])
+                        wl.consume(last, ws[k])
                 i_prev, i = i, i + len(sets)
                 # Window-boundary-aligned periodic snapshot: fire when the
                 # window crossed a checkpoint_every boundary.
@@ -886,8 +895,9 @@ class ElasticEngine:
                     continue
                 settle_recovery(i)
                 reports.append(rep)
-                last = wl.combine(y)
-                w = wl.consume(last, w)
+                with TraceAnnotation("usec.consume"):
+                    last = wl.combine(y)
+                    w = wl.consume(last, w)
                 i += 1
                 if ckpt_every is not None and i % ckpt_every == 0:
                     checkpoint(w, i, f"periodic @ engine step {i}")

@@ -74,6 +74,7 @@ def usec_matvec_padded(
         out_specs=pl.BlockSpec((bm, c), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, c), jnp.float32),
         interpret=interpret,
+        name="usec_matvec",
     )(x, w)
 
 

@@ -101,6 +101,7 @@ def usec_segmented_padded(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, block_rows, c), jnp.float32),
         interpret=interpret,
+        name="usec_segmented",
     )(blk_slot, blk_off_u, staged, w)
 
 

@@ -13,7 +13,7 @@ For each cell this produces, from the compiled artifact alone (no execution):
   * memory_analysis()  — per-device argument/temp bytes (proves it fits HBM)
   * cost_analysis()    — per-device HLO FLOPs / bytes accessed
   * collective bytes   — parsed from the partitioned HLO text, per op kind
-  * the three roofline terms (see benchmarks/roofline.py for the report)
+  * the three roofline terms
 
 Usage:
   python -m repro.launch.dryrun --arch stablelm-1.6b --shape train_4k --mesh single

@@ -216,7 +216,10 @@ class StepReport:
     replanned: bool            # a different plan took effect this step
     plan_cache_hit: bool       # ... and it came from the membership cache
     replan_s: float            # host-side planning latency (solve+compile or cache swap)
-    wall_s: float              # measured device step wall time (jit call, blocked)
+    # Host clock from before the operand put to the end of the blocked
+    # wait: put, executor call and block_until_ready (a fused window's
+    # wall, less an overlapped precompile, over its active steps).
+    wall_s: float
     modeled_completion: float  # max over loaded workers of clocked duration
     straggled: Tuple[int, ...]
     waste: int                 # transition waste vs the previous step's plan
@@ -1593,7 +1596,7 @@ class ElasticRunner:
         cache_hit: bool,
         replanned: bool,
         waste: int,
-        t0: float,
+        replan_s: float,
         injected: Optional[Tuple[int, ...]],
         lost: Tuple[int, ...] = (),
     ) -> Tuple[np.ndarray, StepReport]:
@@ -1612,13 +1615,15 @@ class ElasticRunner:
         ``lost`` (pre-classified, covered dispatch faults) are workers
         whose partial never arrives: they are not dispatched, spend the S
         budget first in the realized set, and are censored from the EWMA.
+        Runs inside :meth:`step`'s ``usec.step`` span.
         """
+        from jax.profiler import TraceAnnotation
+
         from .executor import refresh_include
 
         t = self._step
         slot_d, off_d, goff_d, _include0_d, _nblk_d = entry.dev
         valid_d = entry.dev_valid
-        replan_s = time.perf_counter() - t0
 
         silent = set(lost)
         loaded = [
@@ -1630,80 +1635,89 @@ class ElasticRunner:
         nblk[loaded] = entry.block.n_blocks[loaded]
         self._operand_shape = np.shape(w)
         t1 = time.perf_counter()
-        parts_d = self._worker_exec(
-            self._staged_dev, slot_d, off_d, goff_d, valid_d,
-            self._put_workers(nblk), self._put_replicated(w),
-        )
+        with TraceAnnotation("usec.put"):
+            nblk_d = self._put_workers(nblk)
+            w_d = self._put_replicated(w)
+        with TraceAnnotation("usec.enqueue"):
+            parts_d = self._worker_exec(
+                self._staged_dev, slot_d, off_d, goff_d, valid_d, nblk_d, w_d)
         # Each worker's partial is its own device's shard: fetch the
         # loaded workers' shards one by one, never the whole array.
         shard_of = {s.index[0].start or 0: s.data
                     for s in parts_d.addressable_shards}
         for n in loaded:
-            shard_of[n].block_until_ready()
+            with TraceAnnotation("usec.wait", worker=n):
+                shard_of[n].block_until_ready()
         wall = time.perf_counter() - t1
         self.device_dispatches += 1
         self._last_step_wall = wall
+        with TraceAnnotation("usec.fetch"):
+            parts = [np.asarray(shard_of[n])[0] for n in loaded]
 
-        row_loads = entry.block_loads * self.rows_per_tile
-        # The clock still models EVERY loaded worker (the lost one was
-        # assigned its rows and the speed process must keep its cadence);
-        # censoring happens after the draw — the measurement never arrives.
-        durations = self.clock.durations(row_loads, self._membership, wall)
-        for n in silent:
-            durations.pop(n, None)
-        timed = self._timeout_check(
-            t, entry, durations, silent | set(injected or ()))
-        if timed:
-            silent |= set(timed)
-            for n in timed:
+        with TraceAnnotation("usec.collect"):
+            row_loads = entry.block_loads * self.rows_per_tile
+            # The clock still models EVERY loaded worker (the lost one was
+            # assigned its rows and the speed process must keep its
+            # cadence); censoring happens after the draw — the measurement
+            # never arrives.
+            durations = self.clock.durations(
+                row_loads, self._membership, wall)
+            for n in silent:
                 durations.pop(n, None)
-        parts = [np.asarray(shard_of[n])[0] for n in loaded]
-        silent, durations = self._integrity_first(
-            t, entry, parts, loaded, w, silent, durations, injected)
-        forced = tuple(sorted(silent))
-        if injected is None:
-            realized = self._derive_realized(durations, forced=forced)
-        else:
-            realized = tuple(sorted(set(injected) | silent))
-        # Host-side feasibility + winner weights: include_mask raises when a
-        # segment lost every holder, exactly like the barrier path.
-        include = refresh_include(
-            entry.block, entry.step_plan.plan, realized)
-        y = self._winner_combine(parts, loaded, entry, include)
+            timed = self._timeout_check(
+                t, entry, durations, silent | set(injected or ()))
+            if timed:
+                silent |= set(timed)
+                for n in timed:
+                    durations.pop(n, None)
+            silent, durations = self._integrity_first(
+                t, entry, parts, loaded, w, silent, durations, injected)
+            forced = tuple(sorted(silent))
+            if injected is None:
+                realized = self._derive_realized(durations, forced=forced)
+            else:
+                realized = tuple(sorted(set(injected) | silent))
+            # Host-side feasibility + winner weights: include_mask raises
+            # when a segment lost every holder, exactly like the barrier
+            # path.
+            include = refresh_include(
+                entry.block, entry.step_plan.plan, realized)
+            y = self._winner_combine(parts, loaded, entry, include)
 
-        self._pending_loads = {
-            n: float(entry.block_loads[n]) for n in durations
-        }
-        self._pending_durations = durations
-        if self._take_speed_loss(t):
-            self._pending_loads, self._pending_durations = {}, {}
-        skipped = set(realized)
-        consumed = [d for n, d in durations.items() if n not in skipped]
-        modeled = max(consumed) if consumed else 0.0
+            self._pending_loads = {
+                n: float(entry.block_loads[n]) for n in durations
+            }
+            self._pending_durations = durations
+            if self._take_speed_loss(t):
+                self._pending_loads, self._pending_durations = {}, {}
+            skipped = set(realized)
+            consumed = [d for n, d in durations.items() if n not in skipped]
+            modeled = max(consumed) if consumed else 0.0
 
-        if self.cfg.verify:
-            self._verify(y, w)
+            if self.cfg.verify:
+                self._verify(y, w)
 
-        self._step += 1
-        report = StepReport(
-            step=self._step,
-            available=self._membership,
-            replanned=replanned,
-            plan_cache_hit=cache_hit,
-            replan_s=replan_s,
-            wall_s=wall,
-            modeled_completion=modeled,
-            straggled=realized,
-            waste=waste,
-            jit_cache_size=self.executor_cache_size,
-            measured=durations,
-            speeds_hat=entry.s_plan,
-        )
-        if self.cfg.precompile_neighbors and not cache_hit:
-            t2 = time.perf_counter()
-            self._precompile_neighbors(self._membership)
-            self.precompile_s += time.perf_counter() - t2
-        self._notify_completion([report])
+            self._step += 1
+            report = StepReport(
+                step=self._step,
+                available=self._membership,
+                replanned=replanned,
+                plan_cache_hit=cache_hit,
+                replan_s=replan_s,
+                wall_s=wall,
+                modeled_completion=modeled,
+                straggled=realized,
+                waste=waste,
+                jit_cache_size=self.executor_cache_size,
+                measured=durations,
+                speeds_hat=entry.s_plan,
+            )
+            if self.cfg.precompile_neighbors and not cache_hit:
+                t2 = time.perf_counter()
+                with TraceAnnotation("usec.precompile"):
+                    self._precompile_neighbors(self._membership)
+                self.precompile_s += time.perf_counter() - t2
+            self._notify_completion([report])
         return y, report
 
     def step(
@@ -1731,135 +1745,162 @@ class ElasticRunner:
         the EWMA), uncovered losses raise
         :class:`~repro.faults.chaos.FaultAbort` before anything
         dispatches.
+
+        Under a profiler trace the step is the span ``usec.step`` (its
+        ``step_num`` is the runner's step index) with the children
+        ``usec.plan``, ``usec.put``, ``usec.enqueue``, ``usec.wait``,
+        ``usec.fetch`` and ``usec.collect`` in that order, and
+        ``usec.precompile`` inside ``usec.collect`` after a plan-cache
+        miss.
         """
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
         from .executor import refresh_include
 
-        if event is not None:
-            self.apply_event(event)
         t = self._step
-        self._consult_planning_faults(t)
-        # Tile corruption fires (and is audited + re-staged) BEFORE the
-        # dispatch touches the staged bits: repair is a host copy from a
-        # replica holder, uniform across arrival modes.
-        self._consume_tile_corruption(t)
-        if self._verifying(t):
-            self._audit_and_restage(t)
-        t0 = time.perf_counter()
-        # Feed last step's measured durations into the EWMA (Alg. 1 line 4)
-        # BEFORE planning, so the plan sees the freshest estimates.
-        self.ingest_pending()
-        injected: Optional[Tuple[int, ...]] = None
-        if stragglers is not None:
-            injected = tuple(sorted({int(s) for s in stragglers}))
-            self._check_straggler_ids(injected)
-        lost: Tuple[int, ...] = ()
-        dfaults = self._take_dispatch_faults(t)
-        if dfaults:
-            # Peek the plan BEFORE adoption: an uncovered fault must abort
-            # with the plan/waste accounting untouched, so the re-executed
-            # step replans cleanly after the caller's demotion event.
-            peek, _ = self._plan_for(self._membership)
-            lost = self._resolve_lost(t, peek, dfaults, injected)
-        entry, cache_hit, replanned, waste = self._adopt_plan()
-        gray = self._graylist_forced(
-            t, entry, set(injected or ()) | set(lost))
-        if gray:
-            # Probation: a graylisted worker is a forced realized
-            # straggler — excluded from the combine and the EWMA, plan
-            # (and bits) untouched.
-            lost = tuple(sorted(set(lost) | gray))
-        if self.cfg.arrival == "first":
-            return self._step_first(
-                w, entry, cache_hit, replanned, waste, t0, injected, lost)
-        bad = tuple(sorted(set(injected or ()) | set(lost)))
-        slot_d, off_d, goff_d, include0_d, nblk_d = entry.dev
-        include_d = (
-            include0_d if not bad
-            else self._put_workers(
-                refresh_include(entry.block, entry.step_plan.plan, bad))
-        )
-        replan_s = time.perf_counter() - t0
+        with StepTraceAnnotation("usec.step", step_num=t):
+            with TraceAnnotation("usec.plan"):
+                if event is not None:
+                    self.apply_event(event)
+                self._consult_planning_faults(t)
+                # Tile corruption fires (and is audited + re-staged) BEFORE
+                # the dispatch touches the staged bits: repair is a host
+                # copy from a replica holder, uniform across arrival modes.
+                self._consume_tile_corruption(t)
+                if self._verifying(t):
+                    self._audit_and_restage(t)
+                t0 = time.perf_counter()
+                # Feed last step's measured durations into the EWMA (Alg. 1
+                # line 4) BEFORE planning, so the plan sees the freshest
+                # estimates.
+                self.ingest_pending()
+                injected: Optional[Tuple[int, ...]] = None
+                if stragglers is not None:
+                    injected = tuple(sorted({int(s) for s in stragglers}))
+                    self._check_straggler_ids(injected)
+                lost: Tuple[int, ...] = ()
+                dfaults = self._take_dispatch_faults(t)
+                if dfaults:
+                    # Peek the plan BEFORE adoption: an uncovered fault must
+                    # abort with the plan/waste accounting untouched, so the
+                    # re-executed step replans cleanly after the caller's
+                    # demotion event.
+                    peek, _ = self._plan_for(self._membership)
+                    lost = self._resolve_lost(t, peek, dfaults, injected)
+                entry, cache_hit, replanned, waste = self._adopt_plan()
+                gray = self._graylist_forced(
+                    t, entry, set(injected or ()) | set(lost))
+                if gray:
+                    # Probation: a graylisted worker is a forced realized
+                    # straggler — excluded from the combine and the EWMA,
+                    # plan (and bits) untouched.
+                    lost = tuple(sorted(set(lost) | gray))
+                first = self.cfg.arrival == "first"
+                if not first:
+                    bad = tuple(sorted(set(injected or ()) | set(lost)))
+                    slot_d, off_d, goff_d, include0_d, nblk_d = entry.dev
+                    include_d = (
+                        include0_d if not bad
+                        else self._put_workers(refresh_include(
+                            entry.block, entry.step_plan.plan, bad))
+                    )
+                replan_s = time.perf_counter() - t0
+            if first:
+                return self._step_first(w, entry, cache_hit, replanned,
+                                        waste, replan_s, injected, lost)
 
-        self._operand_shape = np.shape(w)
-        t1 = time.perf_counter()
-        w_dev = self._put_replicated(w)
-        y = self._executor(
-            self._staged_dev,
-            slot_d, off_d, goff_d, include_d, nblk_d, w_dev,
-        )
-        y.block_until_ready()
-        wall = time.perf_counter() - t1
-        self.device_dispatches += 1
-        self._last_step_wall = wall
-        y = np.asarray(y)
-
-        row_loads = entry.block_loads * self.rows_per_tile
-        durations = self.clock.durations(row_loads, self._membership, wall)
-        if lost:
-            # A silent worker's duration is censored — its result never
-            # arrived, so there is no measurement to feed the EWMA (a dead
-            # worker must not poison the estimates it can no longer match).
-            durations = {n: d for n, d in durations.items()
-                         if n not in set(lost)}
-        timed = self._timeout_check(t, entry, durations, set(bad))
-        if timed:
-            # Covered timeout: the barrier master gave up on the late
-            # workers and re-collected from the survivors — one recovery
-            # re-dispatch with the refreshed include weights (same bits:
-            # exactly one surviving copy of every segment delivers).
-            bad = tuple(sorted(set(bad) | set(timed)))
-            include_d = self._put_workers(
-                refresh_include(entry.block, entry.step_plan.plan, bad))
-            t1b = time.perf_counter()
-            y = self._executor(
-                self._staged_dev,
-                slot_d, off_d, goff_d, include_d, nblk_d, w_dev,
-            )
-            y.block_until_ready()
-            wall += time.perf_counter() - t1b
+            self._operand_shape = np.shape(w)
+            t1 = time.perf_counter()
+            with TraceAnnotation("usec.put"):
+                w_dev = self._put_replicated(w)
+            with TraceAnnotation("usec.enqueue"):
+                y = self._executor(
+                    self._staged_dev,
+                    slot_d, off_d, goff_d, include_d, nblk_d, w_dev,
+                )
+            with TraceAnnotation("usec.wait"):
+                y.block_until_ready()
+            wall = time.perf_counter() - t1
             self.device_dispatches += 1
-            y = np.asarray(y)
-            durations = {n: d for n, d in durations.items()
-                         if n not in set(timed)}
-        y, durations, bad = self._integrity_barrier(
-            t, entry, y, w, bad, durations)
-        # The EWMA is fed tile-unit loads (the LP's unit), so estimated
-        # speeds stay consistent with the planner; clocks see row units.
-        self._pending_loads = {
-            n: float(entry.block_loads[n]) for n in durations
-        }
-        self._pending_durations = durations
-        if self._take_speed_loss(t):
-            self._pending_loads, self._pending_durations = {}, {}
-        modeled = max(durations.values()) if durations else 0.0
+            self._last_step_wall = wall
+            with TraceAnnotation("usec.fetch"):
+                y = np.asarray(y)
 
-        if self.cfg.verify:
-            self._verify(y, w)
+            with TraceAnnotation("usec.collect"):
+                row_loads = entry.block_loads * self.rows_per_tile
+                durations = self.clock.durations(
+                    row_loads, self._membership, wall)
+                if lost:
+                    # A silent worker's duration is censored — its result
+                    # never arrived, so there is no measurement to feed the
+                    # EWMA (a dead worker must not poison the estimates it
+                    # can no longer match).
+                    durations = {n: d for n, d in durations.items()
+                                 if n not in set(lost)}
+                timed = self._timeout_check(t, entry, durations, set(bad))
+                if timed:
+                    # Covered timeout: the barrier master gave up on the
+                    # late workers and re-collected from the survivors —
+                    # one recovery re-dispatch with the refreshed include
+                    # weights (same bits: exactly one surviving copy of
+                    # every segment delivers).
+                    bad = tuple(sorted(set(bad) | set(timed)))
+                    include_d = self._put_workers(
+                        refresh_include(entry.block, entry.step_plan.plan,
+                                        bad))
+                    t1b = time.perf_counter()
+                    y = self._executor(
+                        self._staged_dev,
+                        slot_d, off_d, goff_d, include_d, nblk_d, w_dev,
+                    )
+                    y.block_until_ready()
+                    wall += time.perf_counter() - t1b
+                    self.device_dispatches += 1
+                    y = np.asarray(y)
+                    durations = {n: d for n, d in durations.items()
+                                 if n not in set(timed)}
+                y, durations, bad = self._integrity_barrier(
+                    t, entry, y, w, bad, durations)
+                # The EWMA is fed tile-unit loads (the LP's unit), so
+                # estimated speeds stay consistent with the planner; clocks
+                # see row units.
+                self._pending_loads = {
+                    n: float(entry.block_loads[n]) for n in durations
+                }
+                self._pending_durations = durations
+                if self._take_speed_loss(t):
+                    self._pending_loads, self._pending_durations = {}, {}
+                modeled = max(durations.values()) if durations else 0.0
 
-        self._step += 1
-        report = StepReport(
-            step=self._step,
-            available=self._membership,
-            replanned=replanned,
-            plan_cache_hit=cache_hit,
-            replan_s=replan_s,
-            wall_s=wall,
-            modeled_completion=modeled,
-            straggled=bad,
-            waste=waste,
-            jit_cache_size=self.executor_cache_size,
-            measured=durations,
-            speeds_hat=entry.s_plan,
-        )
-        if self.cfg.precompile_neighbors and not cache_hit:
-            # The step's result is already computed — spend the idle tail
-            # batch-compiling the churn neighborhood of the new membership
-            # so the NEXT membership change is a cache hit. This is the
-            # amortized cost that replaces the per-event replan miss.
-            t2 = time.perf_counter()
-            self._precompile_neighbors(self._membership)
-            self.precompile_s += time.perf_counter() - t2
-        self._notify_completion([report])
+                if self.cfg.verify:
+                    self._verify(y, w)
+
+                self._step += 1
+                report = StepReport(
+                    step=self._step,
+                    available=self._membership,
+                    replanned=replanned,
+                    plan_cache_hit=cache_hit,
+                    replan_s=replan_s,
+                    wall_s=wall,
+                    modeled_completion=modeled,
+                    straggled=bad,
+                    waste=waste,
+                    jit_cache_size=self.executor_cache_size,
+                    measured=durations,
+                    speeds_hat=entry.s_plan,
+                )
+                if self.cfg.precompile_neighbors and not cache_hit:
+                    # The step's result is already computed — spend the
+                    # idle tail batch-compiling the churn neighborhood of
+                    # the new membership so the NEXT membership change is a
+                    # cache hit. This is the amortized cost that replaces
+                    # the per-event replan miss.
+                    t2 = time.perf_counter()
+                    with TraceAnnotation("usec.precompile"):
+                        self._precompile_neighbors(self._membership)
+                    self.precompile_s += time.perf_counter() - t2
+                self._notify_completion([report])
         return y, report
 
     def ingest_pending(self) -> None:
@@ -1977,232 +2018,268 @@ class ElasticRunner:
             events = [None] * n_active
         if len(events) != n_active:
             raise ValueError("events and straggler_sets must align per step")
-        # Feed last window's measured durations into the EWMA before any of
-        # this window's planning (Alg. 1 line 4, at window rate). The
-        # engine already did this before assembling the window (so its
-        # plan_is_ready flush decisions see the same estimates _plan_for
-        # will); the call is idempotent for direct step_window users.
-        self.ingest_pending()
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
-        N = self.placement.n_machines
-        bad = np.zeros((K, N), dtype=bool)
-        metas = []
-        had_miss = False
         base = self._step
-        for k in range(n_active):
-            t0 = time.perf_counter()
-            tk = base + k
-            if events[k] is not None:
-                self.apply_event(events[k])
-            # Fault seams fire at assembly time, per step: nothing has
-            # dispatched yet, so an uncovered loss aborts the WHOLE window
-            # cleanly (FaultAbort) with the carry untouched — the engine
-            # demotes, replans, and re-assembles from this window's head.
-            self._consult_planning_faults(tk)
-            # Tile corruption fires (and is audited + re-staged) at
-            # assembly, BEFORE the window dispatches: the engine breaks
-            # windows at fault steps, so a corrupt tile always lands at
-            # a window head and the repair reaches the device copy.
-            self._consume_tile_corruption(tk)
-            if self._verifying(tk):
-                self._audit_and_restage(tk)
-            dfaults = self._take_dispatch_faults(tk)
-            # Result corruption is consumed at assembly but applied (and
-            # detected) post-fetch — the injection perturbs the fetched
-            # host copy, as a corrupt wire transfer would.
-            rspecs = (
-                () if self.fault_injector is None
-                else tuple(self.fault_injector.take(
-                    tk, kinds=("result_corruption",)))
-            )
-            forced: Tuple[int, ...] = ()
-            if dfaults:
-                peek, _ = self._plan_for(self._membership)
-                forced = self._resolve_lost(tk, peek, dfaults, sets[k])
-            entry, cache_hit, replanned, waste = self._adopt_plan()
-            gray = self._graylist_forced(
-                tk, entry, set(forced) | set(sets[k] or ()))
-            if gray:
-                forced = tuple(sorted(set(forced) | gray))
-            had_miss = had_miss or not cache_hit
-            durs_k = None
-            if sets[k] is None:
-                if self.cfg.arrival == "first":
-                    # Derive this step's realized stragglers at assembly
-                    # time: the in-graph include gather needs the bitmask
-                    # before dispatch, so the clock is sampled here (once
-                    # per step, in step order — the cadence the stepwise
-                    # path uses) against the previous dispatch's per-step
-                    # wall as the wall estimate. Silent workers are drawn
-                    # (cadence) then censored (no measurement arrives).
-                    row_loads = entry.block_loads * self.rows_per_tile
-                    durs_k = self.clock.durations(
-                        row_loads, self._membership, self._last_step_wall)
-                    for n in forced:
-                        durs_k.pop(n, None)
-                    timed = self._timeout_check(
-                        tk, entry, durs_k, set(forced))
-                    if timed:
-                        forced = tuple(sorted(set(forced) | set(timed)))
-                        for n in timed:
-                            durs_k.pop(n, None)
-                    sets[k] = self._derive_realized(durs_k, forced=forced)
+        with StepTraceAnnotation("usec.window", step_num=base,
+                                 steps=n_active):
+            with TraceAnnotation("usec.plan"):
+                # Feed last window's measured durations into the EWMA
+                # before any of this window's planning (Alg. 1 line 4, at
+                # window rate). The engine already did this before
+                # assembling the window (so its plan_is_ready flush
+                # decisions see the same estimates _plan_for will); the
+                # call is idempotent for direct step_window users.
+                self.ingest_pending()
+
+                N = self.placement.n_machines
+                bad = np.zeros((K, N), dtype=bool)
+                metas = []
+                had_miss = False
+                for k in range(n_active):
+                    t0 = time.perf_counter()
+                    tk = base + k
+                    if events[k] is not None:
+                        self.apply_event(events[k])
+                    # Fault seams fire at assembly time, per step: nothing
+                    # has dispatched yet, so an uncovered loss aborts the
+                    # WHOLE window cleanly (FaultAbort) with the carry
+                    # untouched — the engine demotes, replans, and
+                    # re-assembles from this window's head.
+                    self._consult_planning_faults(tk)
+                    # Tile corruption fires (and is audited + re-staged) at
+                    # assembly, BEFORE the window dispatches: the engine
+                    # breaks windows at fault steps, so a corrupt tile
+                    # always lands at a window head and the repair reaches
+                    # the device copy.
+                    self._consume_tile_corruption(tk)
+                    if self._verifying(tk):
+                        self._audit_and_restage(tk)
+                    dfaults = self._take_dispatch_faults(tk)
+                    # Result corruption is consumed at assembly but applied
+                    # (and detected) post-fetch — the injection perturbs
+                    # the fetched host copy, as a corrupt wire transfer
+                    # would.
+                    rspecs = (
+                        () if self.fault_injector is None
+                        else tuple(self.fault_injector.take(
+                            tk, kinds=("result_corruption",)))
+                    )
+                    forced: Tuple[int, ...] = ()
+                    if dfaults:
+                        peek, _ = self._plan_for(self._membership)
+                        forced = self._resolve_lost(
+                            tk, peek, dfaults, sets[k])
+                    entry, cache_hit, replanned, waste = self._adopt_plan()
+                    gray = self._graylist_forced(
+                        tk, entry, set(forced) | set(sets[k] or ()))
+                    if gray:
+                        forced = tuple(sorted(set(forced) | gray))
+                    had_miss = had_miss or not cache_hit
+                    durs_k = None
+                    if sets[k] is None:
+                        if self.cfg.arrival == "first":
+                            # Derive this step's realized stragglers at
+                            # assembly time: the in-graph include gather
+                            # needs the bitmask before dispatch, so the
+                            # clock is sampled here (once per step, in step
+                            # order — the cadence the stepwise path uses)
+                            # against the previous dispatch's per-step wall
+                            # as the wall estimate. Silent workers are drawn
+                            # (cadence) then censored (no measurement
+                            # arrives).
+                            row_loads = entry.block_loads * self.rows_per_tile
+                            durs_k = self.clock.durations(
+                                row_loads, self._membership,
+                                self._last_step_wall)
+                            for n in forced:
+                                durs_k.pop(n, None)
+                            timed = self._timeout_check(
+                                tk, entry, durs_k, set(forced))
+                            if timed:
+                                forced = tuple(
+                                    sorted(set(forced) | set(timed)))
+                                for n in timed:
+                                    durs_k.pop(n, None)
+                            sets[k] = self._derive_realized(
+                                durs_k, forced=forced)
+                        else:
+                            sets[k] = tuple(forced)
+                    else:
+                        self._check_straggler_ids(sets[k])
+                        if forced:
+                            sets[k] = tuple(sorted(set(sets[k]) | set(forced)))
+                    if sets[k]:
+                        # Host-side feasibility check (the device gather
+                        # cannot raise): include_mask errors out when a
+                        # segment lost every holder, exactly like the
+                        # stepwise path.
+                        entry.step_plan.plan.include_mask(sets[k])
+                        bad[k, list(sets[k])] = True
+                    metas.append((self._membership, entry, replanned,
+                                  cache_hit, time.perf_counter() - t0, waste,
+                                  durs_k, forced, rspecs))
+            with TraceAnnotation("usec.put"):
+                # Pad inactive tail slots with the last entry's arrays
+                # (masked out in-graph) so the window's shapes never
+                # change. The stacked plan buffers are cached ON DEVICE in
+                # a small LRU keyed by the window's entry sequence:
+                # revisited signatures (steady state, churn/steady
+                # alternation) re-upload nothing but the small mask/carry
+                # buffers — the fused analogue of the stepwise path's
+                # per-entry ``_CacheEntry.dev``.
+                pad_entry = metas[-1][1]
+                entries = tuple(
+                    [m[1] for m in metas] + [pad_entry] * (K - n_active))
+                key = tuple(id(e) for e in entries)
+                cached = self._window_dev.get(key)
+                if cached is None:
+                    blocks = [e.block for e in entries]
+                    stacks = tuple(
+                        self._jax.device_put(np.stack(a),
+                                             self._by_step_worker)
+                        for a in (
+                            [b.blk_slot for b in blocks],
+                            [b.blk_off for b in blocks],
+                            [b.blk_goff for b in blocks],
+                            [b.n_blocks for b in blocks],
+                            [b.blk_prio for b in blocks],
+                            [b.blk_seg_t >= 0 for b in blocks],
+                        )
+                    )
+                    cached = (entries, stacks)
+                    self._window_dev[key] = cached
+                    while len(self._window_dev) > self._window_dev_cap:
+                        self._window_dev.popitem(last=False)
                 else:
-                    sets[k] = tuple(forced)
-            else:
-                self._check_straggler_ids(sets[k])
-                if forced:
-                    sets[k] = tuple(sorted(set(sets[k]) | set(forced)))
-            if sets[k]:
-                # Host-side feasibility check (the device gather cannot
-                # raise): include_mask errors out when a segment lost every
-                # holder, exactly like the stepwise path.
-                entry.step_plan.plan.include_mask(sets[k])
-                bad[k, list(sets[k])] = True
-            metas.append((self._membership, entry, replanned, cache_hit,
-                          time.perf_counter() - t0, waste, durs_k, forced,
-                          rspecs))
-        # Pad inactive tail slots with the last entry's arrays (masked out
-        # in-graph) so the window's shapes never change. The stacked plan
-        # buffers are cached ON DEVICE in a small LRU keyed by the
-        # window's entry sequence: revisited signatures (steady state,
-        # churn/steady alternation) re-upload nothing but the small
-        # mask/carry buffers — the fused analogue of the stepwise path's
-        # per-entry ``_CacheEntry.dev``.
-        pad_entry = metas[-1][1]
-        entries = tuple([m[1] for m in metas] + [pad_entry] * (K - n_active))
-        key = tuple(id(e) for e in entries)
-        cached = self._window_dev.get(key)
-        if cached is None:
-            blocks = [e.block for e in entries]
-            stacks = tuple(
-                self._jax.device_put(np.stack(a), self._by_step_worker)
-                for a in (
-                    [b.blk_slot for b in blocks],
-                    [b.blk_off for b in blocks],
-                    [b.blk_goff for b in blocks],
-                    [b.n_blocks for b in blocks],
-                    [b.blk_prio for b in blocks],
-                    [b.blk_seg_t >= 0 for b in blocks],
+                    self._window_dev.move_to_end(key)
+                active = np.zeros((K,), dtype=bool)
+                active[:n_active] = True
+
+                t1 = time.perf_counter()
+                w_dev = (
+                    w if hasattr(w, "block_until_ready")
+                    else self._put_replicated(w)
                 )
-            )
-            cached = (entries, stacks)
-            self._window_dev[key] = cached
-            while len(self._window_dev) > self._window_dev_cap:
-                self._window_dev.popitem(last=False)
-        else:
-            self._window_dev.move_to_end(key)
-        active = np.zeros((K,), dtype=bool)
-        active[:n_active] = True
+                self._operand_shape = tuple(w_dev.shape)
+                bad_d = self._put_replicated(bad)
+                active_d = self._put_replicated(active)
+            with TraceAnnotation("usec.enqueue"):
+                w_carry, ys_d, ws_d = self._fused(
+                    self._staged_dev, *cached[1], bad_d, active_d, w_dev)
+            self.device_dispatches += 1
+            # Overlap: the dispatch above is asynchronous — spend the
+            # device time on the churn neighborhood's speculative compile
+            # instead of blocking immediately (stepwise pays this after the
+            # fetch).
+            pre_s = 0.0
+            if self.cfg.precompile_neighbors and had_miss:
+                t2 = time.perf_counter()
+                with TraceAnnotation("usec.precompile"):
+                    self._precompile_neighbors(self._membership)
+                pre_s = time.perf_counter() - t2
+                self.precompile_s += pre_s
+            with TraceAnnotation("usec.wait"):
+                ys_d.block_until_ready()
+            wall = time.perf_counter() - t1
+            # wall_s means "executor time" (the stepwise path measures
+            # exactly that and precompiles after the fetch). On the
+            # forced-host-device setups the overlapped precompile contends
+            # for the same CPU, so subtract it rather than bill planning to
+            # the clock/EWMA on miss windows; genuine overlap on a real
+            # accelerator only makes this an under- rather than
+            # over-estimate.
+            wall = max(wall - pre_s, 1e-9)
+            with TraceAnnotation("usec.fetch"):
+                ys = np.asarray(ys_d)[:n_active]
+                ws = np.asarray(ws_d)[:n_active]
 
-        t1 = time.perf_counter()
-        w_dev = (
-            w if hasattr(w, "block_until_ready") else self._put_replicated(w)
-        )
-        self._operand_shape = tuple(w_dev.shape)
-        w_carry, ys_d, ws_d = self._fused(
-            self._staged_dev, *cached[1],
-            self._put_replicated(bad), self._put_replicated(active), w_dev,
-        )
-        self.device_dispatches += 1
-        # Overlap: the dispatch above is asynchronous — spend the device
-        # time on the churn neighborhood's speculative compile instead of
-        # blocking immediately (stepwise pays this after the fetch).
-        pre_s = 0.0
-        if self.cfg.precompile_neighbors and had_miss:
-            t2 = time.perf_counter()
-            self._precompile_neighbors(self._membership)
-            pre_s = time.perf_counter() - t2
-            self.precompile_s += pre_s
-        ys_d.block_until_ready()
-        wall = time.perf_counter() - t1
-        # wall_s means "executor time" (the stepwise path measures exactly
-        # that and precompiles after the fetch). On the forced-host-device
-        # setups the overlapped precompile contends for the same CPU, so
-        # subtract it rather than bill planning to the clock/EWMA on miss
-        # windows; genuine overlap on a real accelerator only makes this
-        # an under- rather than over-estimate.
-        wall = max(wall - pre_s, 1e-9)
-        ys = np.asarray(ys_d)[:n_active]
-        ws = np.asarray(ws_d)[:n_active]
-        if self._integrity is not None or self.fault_injector is not None:
-            # The integrity seam injects / repairs rows in place; a device
-            # fetch view is read-only, so give it a writable copy.
-            ys = np.array(ys)
-        quarantined = self._integrity_window(
-            base, n_active, metas, sets, ys, ws)
+            with TraceAnnotation("usec.collect"):
+                if self._integrity is not None \
+                        or self.fault_injector is not None:
+                    # The integrity seam injects / repairs rows in place; a
+                    # device fetch view is read-only, so give it a writable
+                    # copy.
+                    ys = np.array(ys)
+                quarantined = self._integrity_window(
+                    base, n_active, metas, sets, ys, ws)
 
-        # Per-window per-worker times: the window wall divided over its
-        # active steps is the per-step equivalent the EWMA expects — speeds
-        # stay in tile-units/s, so the drift-invalidation gate keeps
-        # working at any fuse_steps. Loads/durations accumulate over the
-        # window's (possibly different) per-step plans and are reported as
-        # ONE measurement at the next window.
-        per_step_wall = wall / n_active
-        self._last_step_wall = per_step_wall
-        loads_sum: Dict[int, float] = {}
-        dur_sum: Dict[int, float] = {}
-        per_step_durs = []
-        for k in range(n_active):
-            entry = metas[k][1]
-            durs = metas[k][6]
-            forced_k = metas[k][7]
-            if durs is None:
-                row_loads = entry.block_loads * self.rows_per_tile
-                durs = self.clock.durations(
-                    row_loads, metas[k][0], per_step_wall)
-                for n in forced_k:
-                    # Censor silent workers (covered faults): their result
-                    # — and therefore their measurement — never arrived.
-                    durs.pop(n, None)
-            for n in quarantined[k]:
-                # Censor quarantined workers: a corrupt result's timing
-                # is as untrustworthy as its payload.
-                durs.pop(n, None)
-            per_step_durs.append(durs)
-            if self._take_speed_loss(base + k):
-                # This step's report was lost in transit: its durations
-                # stay out of the window's accumulated EWMA feed.
-                continue
-            for n, d in durs.items():
-                loads_sum[n] = loads_sum.get(n, 0.0) \
-                    + float(entry.block_loads[n])
-                dur_sum[n] = dur_sum.get(n, 0.0) + d
-        self._pending_loads = loads_sum
-        self._pending_durations = dur_sum
+                # Per-window per-worker times: the window wall divided over
+                # its active steps is the per-step equivalent the EWMA
+                # expects — speeds stay in tile-units/s, so the
+                # drift-invalidation gate keeps working at any fuse_steps.
+                # Loads/durations accumulate over the window's (possibly
+                # different) per-step plans and are reported as ONE
+                # measurement at the next window.
+                per_step_wall = wall / n_active
+                self._last_step_wall = per_step_wall
+                loads_sum: Dict[int, float] = {}
+                dur_sum: Dict[int, float] = {}
+                per_step_durs = []
+                for k in range(n_active):
+                    entry = metas[k][1]
+                    durs = metas[k][6]
+                    forced_k = metas[k][7]
+                    if durs is None:
+                        row_loads = entry.block_loads * self.rows_per_tile
+                        durs = self.clock.durations(
+                            row_loads, metas[k][0], per_step_wall)
+                        for n in forced_k:
+                            # Censor silent workers (covered faults): their
+                            # result — and therefore their measurement —
+                            # never arrived.
+                            durs.pop(n, None)
+                    for n in quarantined[k]:
+                        # Censor quarantined workers: a corrupt result's
+                        # timing is as untrustworthy as its payload.
+                        durs.pop(n, None)
+                    per_step_durs.append(durs)
+                    if self._take_speed_loss(base + k):
+                        # This step's report was lost in transit: its
+                        # durations stay out of the window's accumulated
+                        # EWMA feed.
+                        continue
+                    for n, d in durs.items():
+                        loads_sum[n] = loads_sum.get(n, 0.0) \
+                            + float(entry.block_loads[n])
+                        dur_sum[n] = dur_sum.get(n, 0.0) + d
+                self._pending_loads = loads_sum
+                self._pending_durations = dur_sum
 
-        if self.cfg.verify:
-            for k in range(n_active):
-                self._verify(ys[k], ws[k])
+                if self.cfg.verify:
+                    for k in range(n_active):
+                        self._verify(ys[k], ws[k])
 
-        reports = []
-        for k, (avail, entry, replanned, cache_hit, replan_s, waste, _d,
-                _f, _r) in enumerate(metas):
-            self._step += 1
-            durs = per_step_durs[k]
-            if self.cfg.arrival == "first":
-                # First-arrival completion: the master stops at the last
-                # CONSUMED worker — realized stragglers finish later but
-                # are not waited on (their durations still feed the EWMA).
-                skipped = set(sets[k])
-                consumed = [d for n, d in durs.items() if n not in skipped]
-            else:
-                consumed = list(durs.values())
-            reports.append(StepReport(
-                step=self._step,
-                available=avail,
-                replanned=replanned,
-                plan_cache_hit=cache_hit,
-                replan_s=replan_s,
-                wall_s=per_step_wall,
-                modeled_completion=max(consumed) if consumed else 0.0,
-                straggled=sets[k],
-                waste=waste,
-                jit_cache_size=self.executor_cache_size,
-                measured=durs,
-                speeds_hat=entry.s_plan,
-            ))
-        self._notify_completion(reports)
+                reports = []
+                for k, (avail, entry, replanned, cache_hit, replan_s, waste,
+                        _d, _f, _r) in enumerate(metas):
+                    self._step += 1
+                    durs = per_step_durs[k]
+                    if self.cfg.arrival == "first":
+                        # First-arrival completion: the master stops at the
+                        # last CONSUMED worker — realized stragglers finish
+                        # later but are not waited on (their durations
+                        # still feed the EWMA).
+                        skipped = set(sets[k])
+                        consumed = [d for n, d in durs.items()
+                                    if n not in skipped]
+                    else:
+                        consumed = list(durs.values())
+                    reports.append(StepReport(
+                        step=self._step,
+                        available=avail,
+                        replanned=replanned,
+                        plan_cache_hit=cache_hit,
+                        replan_s=replan_s,
+                        wall_s=per_step_wall,
+                        modeled_completion=max(consumed) if consumed else 0.0,
+                        straggled=sets[k],
+                        waste=waste,
+                        jit_cache_size=self.executor_cache_size,
+                        measured=durs,
+                        speeds_hat=entry.s_plan,
+                    ))
+                self._notify_completion(reports)
         return w_carry, ys, ws, reports
 
     def _verify(self, y: np.ndarray, w: np.ndarray) -> None:

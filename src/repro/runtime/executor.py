@@ -37,6 +37,11 @@ the same compiled computation, bit for bit):
   realized straggler set is known (:meth:`ElasticRunner.step` with
   ``arrival="first"``).
 
+The three programs are named ``usec_step``, ``usec_window`` and
+``usec_partials``, and each worker's block loop runs under the named scope
+``usec_blocks``: a profiler trace shows ``jit_usec_step`` and the op names
+below it, whatever the Python around them is called.
+
 Every array a step reads is placed once, where the program expects it:
 staged tiles and per-worker plan rows sharded over the worker axis
 (:func:`worker_sharding`), the operand replicated — so no dispatch moves
@@ -407,9 +412,10 @@ def _make_worker_body(
             # of a fused window, whose trip counts are zeroed in-graph)
             # skip the gather+matmul entirely — same contract as the
             # fori_loop path's zero iteration count.
-            y = jax.lax.cond(
-                n_blocks[0] > 0, _compute,
-                lambda: jnp.zeros((rows_total, cols), jnp.float32))
+            with jax.named_scope("usec_blocks"):
+                y = jax.lax.cond(
+                    n_blocks[0] > 0, _compute,
+                    lambda: jnp.zeros((rows_total, cols), jnp.float32))
         else:
             y0 = jnp.zeros((rows_total, cols), jnp.float32)
 
@@ -422,7 +428,8 @@ def _make_worker_body(
                 yb = mm(xb, w2) * blk_include[i]
                 return jax.lax.dynamic_update_slice(y, yb, (blk_goff[i], 0))
 
-            y = jax.lax.fori_loop(0, n_blocks[0], step, y0)
+            with jax.named_scope("usec_blocks"):
+                y = jax.lax.fori_loop(0, n_blocks[0], step, y0)
         if combine:
             y = jax.lax.psum(y, worker_axis)
         # A 1-d operand squeezes back to a vector only when the output width
@@ -458,7 +465,7 @@ def make_matvec_executor(
 ) -> Callable:
     """Build the jitted USEC row-sharded step for a fixed geometry.
 
-    Returns ``step(staged, blk_slot, blk_off, blk_goff, blk_include,
+    Returns ``usec_step(staged, blk_slot, blk_off, blk_goff, blk_include,
     n_blocks, w) -> y`` where array shapes follow :class:`StagedMatrix` /
     :class:`BlockPlan` and ``w`` is (r,) or (r, c). The output is (rows_total,
     [c]) float32, fully reduced.
@@ -479,7 +486,14 @@ def make_matvec_executor(
         worker_axis, rows_total, block_rows, matmul or _default_matmul,
         out_cols, segmented_fn,
     )
-    return jax.jit(_shard(body, mesh, worker_axis))
+    sharded = _shard(body, mesh, worker_axis)
+
+    def usec_step(staged, blk_slot, blk_off, blk_goff, blk_include,
+                  n_blocks, w):
+        return sharded(staged, blk_slot, blk_off, blk_goff, blk_include,
+                       n_blocks, w)
+
+    return jax.jit(usec_step)
 
 
 def make_worker_executor(
@@ -493,7 +507,7 @@ def make_worker_executor(
 ) -> Callable:
     """Build the jitted per-worker partials for first-arrival execution.
 
-    Returns ``partials(staged, blk_slot, blk_off, blk_goff, blk_include,
+    Returns ``usec_partials(staged, blk_slot, blk_off, blk_goff, blk_include,
     n_blocks, w) -> ys`` with the same arguments as
     :func:`make_matvec_executor`; ``ys`` is (N, rows_total[, c]), sharded
     over the worker axis: row n is worker n's **unmasked** partial,
@@ -516,7 +530,14 @@ def make_worker_executor(
         worker_axis, rows_total, block_rows, matmul or _default_matmul,
         out_cols, segmented_fn, combine=False,
     )
-    return jax.jit(_shard(body, mesh, worker_axis, combine=False))
+    sharded = _shard(body, mesh, worker_axis, combine=False)
+
+    def usec_partials(staged, blk_slot, blk_off, blk_goff, blk_include,
+                      n_blocks, w):
+        return sharded(staged, blk_slot, blk_off, blk_goff, blk_include,
+                       n_blocks, w)
+
+    return jax.jit(usec_partials)
 
 
 def make_fused_executor(
@@ -532,7 +553,7 @@ def make_fused_executor(
 ) -> Callable:
     """Build the jitted K-step fused window driver.
 
-    Returns ``window(staged, blk_slot, blk_off, blk_goff, n_blocks,
+    Returns ``usec_window(staged, blk_slot, blk_off, blk_goff, n_blocks,
     blk_prio, blk_valid, bad, active, w) -> (w_out, ys, ws)``:
 
       blk_*:  (K, N, B[, 1+S]) int32 / n_blocks (K, N) — PER-STEP plan
@@ -570,8 +591,8 @@ def make_fused_executor(
     upd = update if update is not None else (lambda y, w: w)
     del fuse_steps  # geometry is carried by the (K, ...) operands
 
-    def window(staged, blk_slot, blk_off, blk_goff, n_blocks,
-               blk_prio, blk_valid, bad, active, w):
+    def usec_window(staged, blk_slot, blk_off, blk_goff, n_blocks,
+                    blk_prio, blk_valid, bad, active, w):
         def sbody(w, xs):
             slot_k, off_k, goff_k, nblk_k, prio_k, valid_k, bad_k, act_k = xs
             include = device_include_weights(prio_k, valid_k, bad_k)
@@ -593,4 +614,4 @@ def make_fused_executor(
         )
         return w_out, ys, ws
 
-    return jax.jit(window, donate_argnums=(7, 8, 9))
+    return jax.jit(usec_window, donate_argnums=(7, 8, 9))

@@ -9,6 +9,8 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -54,7 +56,9 @@ def test_usec_matvec_kernel_compiles(topo, cols):
         _sds((BLOCK_ROWS, DIM), jnp.float32, one),
         _sds((DIM, cols), jnp.float32, one), bm=BLOCK_ROWS, bk=512,
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "%usec_matvec." in text  # the kernel's name in device traces
 
 
 @pytest.mark.parametrize("cols", [1, 8, 128])
@@ -69,7 +73,9 @@ def test_usec_segmented_kernel_compiles(topo, cols):
         _sds((DIM, cols), jnp.float32, one),
         block_rows=BLOCK_ROWS, bk=512,
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "%usec_segmented." in text
 
 
 def _worker_mesh(devices):
@@ -91,6 +97,14 @@ def _step_args(mesh, n, t_stage, b):
     )
 
 
+def _assert_named(text, program):
+    """The program and its block loop carry the names a profiler trace
+    shows: ``jit_<program>`` on the XLA Modules line, ``usec_blocks`` in
+    the ops' scope."""
+    assert f"HloModule jit_{program}," in text
+    assert re.search(rf'op_name="jit\({program}\)/[^"]*usec_blocks/', text)
+
+
 def _fits(compiled):
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
@@ -107,7 +121,9 @@ def test_one_chip_stepwise_executor_compiles(topo):
                               block_rows=BLOCK_ROWS,
                               matmul=executor_matmul("pallas"))
     compiled = ex.lower(*_step_args(mesh, 1, 1, DIM // BLOCK_ROWS)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    _assert_named(text, "usec_step")
     fits, total = _fits(compiled)
     assert fits, total
 
@@ -135,7 +151,10 @@ def test_one_chip_fused_executor_compiles(topo):
         _sds((k, 1), bool, rep), _sds((k,), bool, rep),
         _sds((DIM,), jnp.float32, rep),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "%usec_segmented." in text
+    _assert_named(text, "usec_window")
     fits, total = _fits(compiled)
     assert fits, total
 
@@ -161,6 +180,8 @@ def test_four_chip_executor_compiles(topo, arrival):
         *_step_args(mesh, 4, t_stage, t_stage * rpt // BLOCK_ROWS)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    _assert_named(text, "usec_step" if arrival == "barrier"
+                  else "usec_partials")
     collectives = ("all-reduce", "all-gather", "all-to-all",
                    "collective-permute")
     if arrival == "barrier":
