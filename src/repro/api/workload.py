@@ -316,11 +316,12 @@ class MatVecPowerIteration(MatVec):
         :func:`~repro.runtime.elastic_runner.unit_vector` by construction —
         both sides square, tree-reduce, sqrt, divide and round with the same
         explicit schedule in float32 (the binary-tree reduction pins the
-        order; sqrt and divide are the integer-exact correctly rounded
+        order; sqrt and divide here are the integer-exact correctly rounded
         routines of :func:`~repro.runtime.elastic_runner._normalize`,
         because a TPU's own float32 sqrt and divide are not correctly
-        rounded). This is what makes a fused window's outputs bit-equal to
-        K stepwise steps on every backend.
+        rounded, and the host's native IEEE ops give the same bits). This
+        is what makes a fused window's outputs bit-equal to K stepwise
+        steps on every backend.
 
         The per-step residual/eigenvalue *statistics* stay host-side: the
         engine replays :meth:`consume` on the window's (ys, ws) outputs and

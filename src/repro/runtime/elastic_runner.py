@@ -2334,16 +2334,106 @@ def _float(b, xp):
     return jax.lax.bitcast_convert_type(b, xp.float32)
 
 
+_EXP_BITS = 0x7F800000                          # float32 exponent field
+
+
+def _positive_normal(b):
+    """Where an int32 pattern is a positive normal float32: the domain of
+    :func:`_rn_sqrt` and of :func:`_rn_div`'s divisor."""
+    return (b >= 0x00800000) & (b < _EXP_BITS)
+
+
+def _sqrt_repair(s):
+    """Host mask of the radicands whose native ``np.sqrt`` may not carry
+    :func:`_rn_sqrt_int`'s bits: those outside the positive normals (zero,
+    negative, subnormal, inf, NaN). A positive normal radicand has a normal
+    root, which both round to nearest even. Chosen from the int32 pattern,
+    so the host thread's FTZ/DAZ mode cannot move it."""
+    return ~_positive_normal(_bits(s, np))
+
+
+def _div_repair(a, d, q):
+    """Host mask of the entries of the native float32 quotient ``q = a / d``
+    that may not carry :func:`_rn_div_int`'s bits, from int32 patterns only
+    (FTZ/DAZ cannot move it). All of them when ``d`` is not one positive
+    normal scalar, the domain :func:`_rn_div` documents. Else the entries
+    whose ``a`` is non-zero and
+
+    - subnormal, inf or NaN, or
+    - whose native quotient's exponent field is 0, 1 or all ones: an
+      underflow or flush, the quotients that round up to 2^-126 (the
+      integer routine rounds to 24 bits there, IEEE to the subnormal
+      grid: ``(2 - 2^-23) / 2^127``), or an overflow.
+
+    Any other quotient is normal in both and rounded to nearest even from
+    the same exact value, so the bits agree."""
+    bd = _bits(d, np)
+    if bd.size != 1 or not _positive_normal(bd).all():
+        return np.ones(q.shape, bool)
+    ba, bq = _bits(a, np), _bits(q, np)
+    ea, eq = ba & _EXP_BITS, (bq >> 23) & 0xFF
+    return ((ba & 0x7FFFFFFF) != 0) & (
+        (ea == 0) | (ea == _EXP_BITS) | (eq <= 1) | (eq == 0xFF))
+
+
+def _repaired(out, fix, exact):
+    """``out`` with the entries under the mask ``fix`` taken from
+    ``exact(fix)``, the integer routine run on those entries alone. Under a
+    profiler trace a repair is the span ``usec.rn_repair`` (``entries``: how
+    many); none is entered when nothing needs it."""
+    n = int(np.count_nonzero(fix))
+    if not n:
+        return out
+    from jax.profiler import TraceAnnotation
+
+    with TraceAnnotation("usec.rn_repair", entries=n):
+        out = np.array(out, np.float32)
+        out[fix] = exact(fix)
+    return out
+
+
 def _rn_sqrt(s, xp):
-    """Correctly rounded float32 ``sqrt`` of a positive normal scalar, by
-    integer digit-by-digit square root.
+    """Correctly rounded float32 ``sqrt`` of a positive normal scalar.
 
     A TPU's float32 ``sqrt`` and divide are not correctly rounded (they
-    refine a hardware estimate), so a device normalize built on them
-    disagrees with NumPy in the last ulp. This routine and
-    :func:`_rn_div` use only int32 shifts, adds and compares — exact on
-    every backend — and return the IEEE round-to-nearest-even result, the
-    same bits as ``np.sqrt``."""
+    refine a hardware estimate), so the device (``xp`` is jax.numpy) runs
+    :func:`_rn_sqrt_int`, built from int32 ops that are exact on every
+    backend. The host's own ``np.sqrt`` is IEEE round-to-nearest-even, the
+    same bits on the domain, so NumPy takes it and repairs only the
+    entries :func:`_sqrt_repair` marks, by the integer routine: the host
+    result equals :func:`_rn_sqrt_int`'s on every input."""
+    if xp is not np:
+        return _rn_sqrt_int(s, xp)
+    s = np.asarray(s, np.float32)
+    with np.errstate(all="ignore"):
+        out = np.sqrt(s)
+    return _repaired(out, _sqrt_repair(s),
+                     lambda m: _rn_sqrt_int(s[m], np))
+
+
+def _rn_div(a, d, xp):
+    """Correctly rounded float32 ``a / d`` for normal or zero ``a`` and a
+    positive normal scalar ``d``.
+
+    The device runs :func:`_rn_div_int` (see :func:`_rn_sqrt` for why).
+    The host takes NumPy's native IEEE divide and repairs only the entries
+    :func:`_div_repair` marks, by the integer routine: the host result
+    equals :func:`_rn_div_int`'s on every input, which the fused window's
+    parity with the stepwise host path rests on."""
+    if xp is not np:
+        return _rn_div_int(a, d, xp)
+    a, d = np.asarray(a, np.float32), np.asarray(d, np.float32)
+    with np.errstate(all="ignore"):
+        q = a / d
+    return _repaired(q, _div_repair(a, d, q),
+                     lambda m: _rn_div_int(a[m], d, np))
+
+
+def _rn_sqrt_int(s, xp):
+    """Correctly rounded float32 ``sqrt`` of a positive normal scalar, by
+    integer digit-by-digit square root: only int32 shifts, adds and
+    compares, exact on every backend, returning the IEEE
+    round-to-nearest-even result (the same bits as ``np.sqrt``)."""
     b = _bits(s, xp)
     e = (b >> 23) & 0xFF
     m = (b & 0x7FFFFF) | 0x800000               # s = m * 2^(e - 150)
@@ -2369,11 +2459,11 @@ def _rn_sqrt(s, xp):
     return _float((exp << 23) | (mant & 0x7FFFFF), xp)
 
 
-def _rn_div(a, d, xp):
+def _rn_div_int(a, d, xp):
     """Correctly rounded float32 ``a / d`` for normal or zero ``a`` and a
-    positive normal scalar ``d``, by integer long division (see
-    :func:`_rn_sqrt` for why). Same bits as NumPy's ``a / d`` whenever the
-    quotient is normal."""
+    positive normal scalar ``d``, by integer long division (int32 ops
+    only, as :func:`_rn_sqrt_int`). Same bits as NumPy's ``a / d``
+    whenever the quotient is normal."""
     ba, bd = _bits(a, xp), _bits(d, xp)
     ea, ed = (ba >> 23) & 0xFF, (bd >> 23) & 0xFF
     ma = (ba & 0x7FFFFF) | 0x800000
@@ -2405,7 +2495,10 @@ def _normalize(v, xp):
     :func:`_tree_sumsq` norm, then correctly rounded sqrt and divide
     (:func:`_rn_sqrt`, :func:`_rn_div`). Every op is exact given its
     inputs on every backend, so NumPy and a TPU produce the SAME bits —
-    the fused window's device update is bitwise-equal to the host's."""
+    the fused window's device update is bitwise-equal to the host's. The
+    device computes sqrt and divide by the integer routines; the host by
+    its native IEEE ops, with any entry out of their shared domain
+    repaired by the integer routine (span ``usec.rn_repair``)."""
     return _rn_div(v, _rn_sqrt(_tree_sumsq(v, xp), xp), xp)
 
 
@@ -2439,9 +2532,13 @@ def quantize_unit(v: np.ndarray, bits: int = 8) -> np.ndarray:
     schedule that jax reproduces bit for bit on every backend, so the fused
     device driver can run the SAME update in-graph
     (:meth:`~repro.api.workload.MatVecPowerIteration.fused_update`) and a
-    K-step window stays bitwise-equal to K stepwise host updates. (Snapping
-    to the grid makes the precision difference vs the old float64 normalize
-    immaterial; the grid exactness argument above is unchanged.)
+    K-step window stays bitwise-equal to K stepwise host updates. Here on
+    the host the sqrt and divide are NumPy's native IEEE ops, which give
+    the device's integer routines' bits; an entry outside their shared
+    domain is repaired by the integer routine (span ``usec.rn_repair``),
+    which a grid-valued product never needs. (Snapping to the grid makes
+    the precision difference vs the old float64 normalize immaterial; the
+    grid exactness argument above is unchanged.)
     """
     v = np.asarray(v, dtype=np.float32)
     u = _normalize(v, np)
