@@ -226,6 +226,10 @@ class StepReport:
     jit_cache_size: int        # executor compile count so far (stays 1)
     measured: Dict[int, float] # per-worker durations fed to the EWMA next step
     speeds_hat: np.ndarray     # estimator state the plan was built under
+    # First arrival only: host clock of the partials' fetch plus the
+    # combine (include refresh and winner gather); 0 on the barrier and
+    # fused paths, whose combine runs on the device.
+    combine_s: float = 0.0
 
 
 # ---------------------------------------------------------------------- #
@@ -1615,7 +1619,10 @@ class ElasticRunner:
         ``lost`` (pre-classified, covered dispatch faults) are workers
         whose partial never arrives: they are not dispatched, spend the S
         budget first in the realized set, and are censored from the EWMA.
-        Runs inside :meth:`step`'s ``usec.step`` span.
+        Runs inside :meth:`step`'s ``usec.step`` span; the include refresh
+        and winner gather are the span ``usec.combine`` inside
+        ``usec.collect``, and the report's ``combine_s`` times them with
+        the fetch.
         """
         from jax.profiler import TraceAnnotation
 
@@ -1651,8 +1658,10 @@ class ElasticRunner:
         wall = time.perf_counter() - t1
         self.device_dispatches += 1
         self._last_step_wall = wall
+        t2 = time.perf_counter()
         with TraceAnnotation("usec.fetch"):
             parts = [np.asarray(shard_of[n])[0] for n in loaded]
+        fetch_s = time.perf_counter() - t2
 
         with TraceAnnotation("usec.collect"):
             row_loads = entry.block_loads * self.rows_per_tile
@@ -1680,9 +1689,12 @@ class ElasticRunner:
             # Host-side feasibility + winner weights: include_mask raises
             # when a segment lost every holder, exactly like the barrier
             # path.
-            include = refresh_include(
-                entry.block, entry.step_plan.plan, realized)
-            y = self._winner_combine(parts, loaded, entry, include)
+            t3 = time.perf_counter()
+            with TraceAnnotation("usec.combine"):
+                include = refresh_include(
+                    entry.block, entry.step_plan.plan, realized)
+                y = self._winner_combine(parts, loaded, entry, include)
+            combine_s = fetch_s + time.perf_counter() - t3
 
             self._pending_loads = {
                 n: float(entry.block_loads[n]) for n in durations
@@ -1711,6 +1723,7 @@ class ElasticRunner:
                 jit_cache_size=self.executor_cache_size,
                 measured=durations,
                 speeds_hat=entry.s_plan,
+                combine_s=combine_s,
             )
             if self.cfg.precompile_neighbors and not cache_hit:
                 t2 = time.perf_counter()
@@ -1751,7 +1764,8 @@ class ElasticRunner:
         ``usec.plan``, ``usec.put``, ``usec.enqueue``, ``usec.wait``,
         ``usec.fetch`` and ``usec.collect`` in that order, and
         ``usec.precompile`` inside ``usec.collect`` after a plan-cache
-        miss.
+        miss; on first arrival ``usec.combine`` sits inside
+        ``usec.collect``.
         """
         from jax.profiler import StepTraceAnnotation, TraceAnnotation
 

@@ -128,6 +128,13 @@ runner = ElasticRunner(
 )
 script = {0: ((2,), ()), 1: ((), (2,)), 2: ((0,), ()), 4: ((), (0,))}
 picker = np.random.default_rng(1)
+# Which step each on-demand plan solve ran in.
+solved_at = []
+solve = runner.planning_master.plan_step
+def plan_step(*args, **kwargs):
+    solved_at.append(runner._step)
+    return solve(*args, **kwargs)
+runner.planning_master.plan_step = plan_step
 res = run_power_iteration(
     runner, 7, events=scripted_trace(4, script),
     straggler_sets=lambda i, avail: (int(picker.choice(avail)),),
@@ -139,10 +146,15 @@ assert res.plans_compiled >= 2       # membership changes forced fresh plans
 assert res.cache_hits >= 1           # ... and revisits reused them
 assert res.total_waste >= 0
 assert res.residuals[-1] < res.residuals[0]   # power iteration converging
-# cache-hit replans must be far cheaper than compile replans
-hit = [r.replan_s for r in res.reports if r.plan_cache_hit]
-miss = [r.replan_s for r in res.reports if r.replanned and not r.plan_cache_hit]
-assert hit and miss and min(miss) > max(hit)
+# A cache-hit replan adopts a memoized plan and solves nothing; a miss
+# solves once. (Counted, not timed: on a loaded host a hit's drift probe
+# and a miss's small solve are a few ms apart at most.)
+hit = [r.step - 1 for r in res.reports if r.plan_cache_hit]
+miss = [r.step - 1 for r in res.reports
+        if r.replanned and not r.plan_cache_hit]
+assert hit and miss
+assert not set(hit) & set(solved_at), (hit, solved_at)
+assert sorted(solved_at) == miss, (miss, solved_at)
 print("RUNNER-OK", res.plans_compiled, res.cache_hits, res.churn_events)
 """, n_devices=4)
     assert "RUNNER-OK" in out

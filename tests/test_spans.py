@@ -5,9 +5,11 @@ host spans: ``usec.run`` around the call, one ``usec.step`` (stepwise and
 first arrival) or ``usec.window`` (fused) per dispatch, named by its
 ``step_num``, the phases ``usec.plan``, ``usec.put``, ``usec.enqueue``,
 ``usec.wait``, ``usec.fetch`` and ``usec.collect`` inside it, and one
-``usec.consume`` per engine step. Each mode runs in a subprocess on four
-forced host devices, once traced and once not, and the results must be
-bitwise equal.
+``usec.consume`` per engine step. On first arrival the host's include
+refresh and winner gather are ``usec.combine`` inside ``usec.collect``,
+timed with the fetch by ``StepReport.combine_s`` (0 elsewhere). Each mode
+runs in a subprocess on four forced host devices, once traced and once
+not, and the results must be bitwise equal.
 """
 
 import json
@@ -74,6 +76,7 @@ same = (np.array_equal(plain.result.eigvec, traced.result.eigvec)
 print(json.dumps({{
     "same": bool(same),
     "lines": spans_of(path),
+    "combine_s": [r.combine_s for r in plain.reports + traced.reports],
     "lowered": eng.runner.lowered_step_text().splitlines()[0],
 }}))
 """
@@ -142,6 +145,16 @@ def test_engine_spans_nest_per_step(n, k, arrival, program):
         collapsed = [nm for j, nm in enumerate(seq)
                      if j == 0 or seq[j - 1] != nm]
         assert collapsed == phases, collapsed
+    combines = [i for i, nm in enumerate(name) if nm == "usec.combine"]
+    if arrival == "first":
+        # One combine per step, inside that step's collect.
+        assert len(combines) == steps
+        assert all(name[parent[i]] == "usec.collect"
+                   and parent[parent[i]] in tops for i in combines)
+        assert all(c > 0 for c in out["combine_s"])
+    else:
+        assert not combines
+        assert all(c == 0 for c in out["combine_s"])
     pre = [i for i, nm in enumerate(name) if nm == "usec.precompile"]
     for i in pre:
         assert name[parent[i]] in ("usec.collect", "usec.window")
