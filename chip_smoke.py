@@ -11,7 +11,7 @@ Every phase enters through the front doors a user calls —
 exact and each result is compared bit for bit with a float64 host
 reference.
 
-One chip: power iteration stepwise (8 steps, per-block kernel loop) and
+One chip: power iteration stepwise (8 steps, streamed block list) and
 fused (16 steps in windows of 8, segmented kernel); an exact matmat with
 128 columns; an ``ElasticServer`` answering 16 requests. Four chips: N=4
 workers (cyclic J=3, S=1) under scripted churn, barrier and first
@@ -78,7 +78,7 @@ def _kernel_in(runner) -> bool:
 
 
 def power_iteration(x, seed, kernel_mode=None):
-    """Stepwise (per-block loop) then fused (segmented kernel) power
+    """Stepwise (streamed block list) then fused (segmented kernel) power
     iteration on one worker; the fused run's first 8 steps must equal the
     stepwise run bit for bit."""
     from repro.api import ElasticEngine, EngineConfig, MatVecPowerIteration

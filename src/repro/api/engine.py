@@ -64,9 +64,13 @@ class EngineConfig:
         masks are an in-graph gather, and a churn event mid-window flushes
         the window early (bitwise-equal to stepwise execution). Workloads
         whose ``fused_update`` returns None fall back to stepwise.
-      segmented: block-list execution mode (None = per-block loop;
+      segmented: block-list execution mode. None derives it: a linear
+        workload's one-column operand streams each worker's block list
+        from its staged tiles in one ``usec_stream`` kernel where the
+        kernels run as Pallas (on TPU, or ``matmul_mode`` "pallas" /
+        "interpret"); everything else keeps the per-block loop.
         "auto"/"pallas"/"interpret"/"ref" = the segment-aware whole-list
-        path, see :class:`~repro.runtime.elastic_runner.RunnerConfig`).
+        path, see :class:`~repro.runtime.elastic_runner.RunnerConfig`.
       dispatch_timeout: modeled per-dispatch deadline (seconds). A worker
         whose clocked duration exceeds it is treated as silent: masked as
         a realized straggler when the S budget covers it, demoted +

@@ -21,7 +21,11 @@ import jax.numpy as jnp
 from . import ref
 from .flash_attention import flash_attention_padded
 from .usec_matvec import usec_matvec_padded
-from .usec_segmented import segmented_gather_ref, usec_segmented_padded
+from .usec_segmented import (
+    segmented_gather_ref,
+    usec_segmented_padded,
+    usec_stream_padded,
+)
 
 
 def _on_tpu() -> bool:
@@ -153,6 +157,47 @@ def usec_segmented(
             block_rows=block_rows, bk=bk, interpret=(mode == "interpret"),
         )
     return compact * blk_include[:, None, None]
+
+
+def usec_stream(
+    staged: jnp.ndarray,
+    blk_slot: jnp.ndarray,
+    blk_off: jnp.ndarray,
+    blk_goff: jnp.ndarray,
+    blk_include: jnp.ndarray,
+    n_blocks: jnp.ndarray,
+    w: jnp.ndarray,
+    rows_total: int,
+    block_rows: int,
+    mode: str = "pallas",
+) -> jnp.ndarray:
+    """A worker's whole block list against a one-column operand, streamed
+    from its staged tiles in one ``pallas_call``: (rows_total, 1) float32.
+
+    Row ``blk_goff[i] + r`` of each block ``i < n_blocks`` holds
+    ``(staged[blk_slot[i], blk_off[i] + r] . w) * blk_include[i]`` — the
+    product, then the include weight, as the per-block loop computes it —
+    and every other row 0 (:mod:`repro.kernels.usec_segmented`).
+
+    staged: (T, rows_per_tile, K); the plan rows (B,) (offsets in rows,
+    block-aligned); n_blocks: (1,) the blocks in use; w: (K, 1) or (K,).
+    Needs :func:`~repro.kernels.usec_segmented.stream_fits`.
+
+    mode: "pallas" | "interpret".
+    """
+    if mode not in ("pallas", "interpret"):
+        raise ValueError(f"usec_stream mode must be 'pallas' or "
+                         f"'interpret', got {mode!r}")
+    k = staged.shape[-1]
+    y = usec_stream_padded(
+        staged, jnp.reshape(n_blocks, (1,)).astype(jnp.int32),
+        blk_slot.astype(jnp.int32),
+        (blk_off // block_rows).astype(jnp.int32),
+        blk_goff.astype(jnp.int32), blk_include.astype(jnp.float32),
+        jnp.reshape(w, (1, k)), rows_total=rows_total,
+        block_rows=block_rows, interpret=(mode == "interpret"),
+    )
+    return y.reshape(-1)[:rows_total, None]
 
 
 _EXECUTOR_KERNELS = {

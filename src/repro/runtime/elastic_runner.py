@@ -50,10 +50,11 @@ wall time is always measured and reported (steps/sec telemetry).
 
 from __future__ import annotations
 
+import functools
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
+from typing import Callable, Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -117,13 +118,20 @@ class RunnerConfig:
       window costs one dispatch + one result fetch for K steps. Windows are
       always K long in the graph (flushed/tail steps are inactive padding),
       so the fused executor compiles exactly once.
-    segmented: per-worker block-list execution — None keeps the per-block
-      ``fori_loop``; "auto"/"pallas"/"interpret"/"ref" route the whole
-      block list through the workload's ``segmented_fn`` (the
-      scalar-prefetched Pallas kernel on TPU, one gathered flat matmul on
-      CPU). Accumulation order differs from the loop in the last ulp on
-      non-exact data (on the integer-grid matrices of the examples and
-      parity tests, all paths agree bitwise).
+    segmented: per-worker block-list execution. None derives the path
+      (:func:`stream_block_fn`): a linear workload (``MatVec``,
+      ``MatMat``) whose kernels run as Pallas — on TPU by default, or by an
+      explicit ``matmul_mode`` of "pallas"/"interpret" — streams a
+      one-column operand's whole block list from the staged tiles in one
+      ``usec_stream`` kernel; every other case (more than one column,
+      non-linear workloads, CPU defaults, ``matmul_mode="ref"``) keeps the
+      per-block ``fori_loop``. "auto"/"pallas"/"interpret"/"ref" route the
+      whole block list through the workload's ``segmented_fn`` (the
+      scalar-prefetched ``usec_segmented`` kernel on TPU, one gathered flat
+      matmul on CPU). Accumulation order differs from the loop in the last
+      ulp on non-exact data (on the integer-grid matrices of the examples
+      and parity tests, all paths agree bitwise).
+      :attr:`ElasticRunner.block_path` names the path compiled.
     arrival: the master's consume rule. ``"barrier"`` (legacy) blocks on
       every included worker — the psum combine needs all shards.
       ``"first"`` implements the paper's first-arrival master: workers are
@@ -198,6 +206,34 @@ class RunnerConfig:
             raise ValueError(
                 f"dispatch_timeout must be > 0 (modeled seconds), got "
                 f"{self.dispatch_timeout}")
+
+
+def stream_block_fn(workload, cfg: RunnerConfig, platform: str,
+                    width: int, rows_total: int) -> Optional[Callable]:
+    """The streamed block-list kernel the step programs take for a
+    one-column operand (:func:`repro.kernels.ops.usec_stream`, bound to
+    this geometry), or None where the runner keeps its configured path.
+
+    Derived from what the runner can observe, with no option of its own:
+    ``segmented`` left at None, a ``linear`` workload, a row width,
+    ``block_rows`` and row count the kernel takes, and kernels that run
+    as Pallas — ``matmul_mode``, or by default the mesh's platform (Pallas
+    on TPU, the jnp reference elsewhere). The operand's column count is
+    decided when the step program is traced
+    (:func:`~repro.runtime.executor.block_list_path`).
+    """
+    from repro.kernels.ops import usec_stream
+    from repro.kernels.usec_segmented import stream_fits
+
+    if cfg.segmented is not None or not getattr(workload, "linear", False):
+        return None
+    if not stream_fits(width, cfg.block_rows, rows_total):
+        return None
+    mode = cfg.matmul_mode or ("pallas" if platform == "tpu" else "ref")
+    if mode not in ("pallas", "interpret"):
+        return None
+    return functools.partial(usec_stream, rows_total=rows_total,
+                             block_rows=cfg.block_rows, mode=mode)
 
 
 def _validate_choice(name: str, value, allowed) -> None:
@@ -454,11 +490,14 @@ class ElasticRunner:
             seg_mode = None if cfg.segmented == "auto" else cfg.segmented
             seg_fn = workload.segmented_fn(seg_mode,
                                            block_rows=cfg.block_rows)
+        stream_fn = stream_block_fn(
+            workload, cfg, self.mesh.devices.flat[0].platform, x.shape[1], q)
+        self._seg_fn, self._stream_fn = seg_fn, stream_fn
         self._executor = make_matvec_executor(
             self.mesh, worker_axis, rows_total=q, block_rows=cfg.block_rows,
             matmul=workload.executor_fn(cfg.matmul_mode),
             out_cols=workload.out_cols,
-            segmented_fn=seg_fn,
+            segmented_fn=seg_fn, stream_fn=stream_fn,
         )
         # First-arrival mode computes per-worker partials (no psum), each
         # on its own worker's device; ONE compiled program serves every
@@ -470,7 +509,7 @@ class ElasticRunner:
                 block_rows=cfg.block_rows,
                 matmul=workload.executor_fn(cfg.matmul_mode),
                 out_cols=workload.out_cols,
-                segmented_fn=seg_fn,
+                segmented_fn=seg_fn, stream_fn=stream_fn,
             )
         # The fused window driver shares the stepwise per-worker body; the
         # workload's fused_update is the in-graph iterate step. None means
@@ -488,7 +527,7 @@ class ElasticRunner:
                     block_rows=cfg.block_rows, fuse_steps=cfg.fuse_steps,
                     matmul=workload.executor_fn(cfg.matmul_mode),
                     out_cols=workload.out_cols, update=upd,
-                    segmented_fn=seg_fn,
+                    segmented_fn=seg_fn, stream_fn=stream_fn,
                 )
         # Placement: worker n's staged tiles and plan rows live on worker
         # n's device (the layout the shard_map in_specs ask for, so no
@@ -724,6 +763,25 @@ class ElasticRunner:
             fn = self._worker_exec or self._executor
             args = (self._staged_dev, *self._current.dev, w)
         return fn.lower(*args).as_text()
+
+    @property
+    def block_path(self) -> Optional[str]:
+        """The block-list path the step programs compiled: ``"stream"``,
+        ``"segmented"`` or ``"loop"`` (None before the first compile)."""
+        for f in (self._fused, self._worker_exec, self._executor):
+            if f is not None and f.block_paths:
+                return f.block_paths[-1]
+        return None
+
+    def _step_path(self) -> str:
+        """The block-list path a step on the last dispatched operand
+        runs (the ``path`` of its ``usec.enqueue`` span)."""
+        from .executor import block_list_path
+
+        shape = self._operand_shape
+        cols = shape[1] if len(shape) == 2 else 1
+        return block_list_path(self._stream_fn, self._seg_fn, cols,
+                               self.workload.out_cols)
 
     @property
     def executor_cache_size(self) -> int:
@@ -1645,7 +1703,7 @@ class ElasticRunner:
         with TraceAnnotation("usec.put"):
             nblk_d = self._put_workers(nblk)
             w_d = self._put_replicated(w)
-        with TraceAnnotation("usec.enqueue"):
+        with TraceAnnotation("usec.enqueue", path=self._step_path()):
             parts_d = self._worker_exec(
                 self._staged_dev, slot_d, off_d, goff_d, valid_d, nblk_d, w_d)
         # Each worker's partial is its own device's shard: fetch the
@@ -1827,7 +1885,7 @@ class ElasticRunner:
             t1 = time.perf_counter()
             with TraceAnnotation("usec.put"):
                 w_dev = self._put_replicated(w)
-            with TraceAnnotation("usec.enqueue"):
+            with TraceAnnotation("usec.enqueue", path=self._step_path()):
                 y = self._executor(
                     self._staged_dev,
                     slot_d, off_d, goff_d, include_d, nblk_d, w_dev,
@@ -2178,7 +2236,7 @@ class ElasticRunner:
                 self._operand_shape = tuple(w_dev.shape)
                 bad_d = self._put_replicated(bad)
                 active_d = self._put_replicated(active)
-            with TraceAnnotation("usec.enqueue"):
+            with TraceAnnotation("usec.enqueue", path=self._step_path()):
                 w_carry, ys_d, ws_d = self._fused(
                     self._staged_dev, *cached[1], bad_d, active_d, w_dev)
             self.device_dispatches += 1

@@ -37,8 +37,12 @@ the same compiled computation, bit for bit):
   realized straggler set is known (:meth:`ElasticRunner.step` with
   ``arrival="first"``).
 
+On TPU a one-column linear step streams each worker's block list from its
+staged tiles in one Pallas kernel (``usec_stream``) instead of the loop:
+the same rows, the same products, the same include order.
+
 The three programs are named ``usec_step``, ``usec_window`` and
-``usec_partials``, and each worker's block loop runs under the named scope
+``usec_partials``, and each worker's block list runs under the named scope
 ``usec_blocks``: a profiler trace shows ``jit_usec_step`` and the op names
 below it, whatever the Python around them is called.
 
@@ -364,6 +368,21 @@ def worker_sharding(mesh: jax.sharding.Mesh, worker_axis: str,
     return NamedSharding(mesh, P(*([None] * lead), worker_axis))
 
 
+def block_list_path(
+    stream_fn: Optional[Callable],
+    segmented_fn: Optional[Callable],
+    cols: int,
+    out_cols: Optional[int],
+) -> str:
+    """Which block-list path a worker body compiles: ``"stream"`` (one
+    ``stream_fn`` call over the whole list, for a one-column operand whose
+    output width follows it), ``"segmented"`` (``segmented_fn``) or
+    ``"loop"`` (the per-block ``fori_loop``)."""
+    if stream_fn is not None and cols == 1 and out_cols is None:
+        return "stream"
+    return "segmented" if segmented_fn is not None else "loop"
+
+
 def _make_worker_body(
     worker_axis: str,
     rows_total: int,
@@ -372,9 +391,18 @@ def _make_worker_body(
     out_cols: Optional[int],
     segmented_fn: Optional[Callable],
     combine: bool = True,
+    stream_fn: Optional[Callable] = None,
 ):
     """The per-worker, per-step computation shared by all three executors —
     ONE definition so the drivers are the same compiled math.
+
+    The block-list path is chosen at trace time by :func:`block_list_path`
+    and appended to ``body.paths``. With ``stream_fn`` and a one-column
+    operand the worker's whole block list is one call,
+    ``stream_fn(staged, blk_slot, blk_off, blk_goff, blk_include,
+    n_blocks, w2) -> (rows_total, 1)``: the block products times their
+    include weights on the blocks' global rows, 0 elsewhere — exactly the
+    loop's output (the Pallas ``usec_stream`` kernel).
 
     With ``segmented_fn`` the per-block ``fori_loop`` is replaced by one
     whole-block-list call (the segment-aware kernel path): ``segmented_fn``
@@ -396,9 +424,15 @@ def _make_worker_body(
         blk_goff, blk_include = blk_goff[0], blk_include[0]
         w2 = w if w.ndim == 2 else w[:, None]
         cols = w2.shape[1] if out_cols is None else out_cols
+        path = block_list_path(stream_fn, segmented_fn, w2.shape[1],
+                               out_cols)
+        body.paths.append(path)
 
-        if segmented_fn is not None:
+        if path != "loop":
             def _compute():
+                if path == "stream":
+                    return stream_fn(staged, blk_slot, blk_off, blk_goff,
+                                     blk_include, n_blocks, w2)
                 compact = segmented_fn(staged, blk_slot, blk_off,
                                        blk_include, w2)
                 rows = (
@@ -410,7 +444,7 @@ def _make_worker_body(
 
             # Zero-trip workers (preempted machines; inactive padding steps
             # of a fused window, whose trip counts are zeroed in-graph)
-            # skip the gather+matmul entirely — same contract as the
+            # skip the block list entirely — same contract as the
             # fori_loop path's zero iteration count.
             with jax.named_scope("usec_blocks"):
                 y = jax.lax.cond(
@@ -437,7 +471,15 @@ def _make_worker_body(
         y = y if (w.ndim == 2 or out_cols is not None) else y[:, 0]
         return y if combine else y[None]
 
+    body.paths = []
     return body
+
+
+def _with_paths(jitted, body):
+    """Expose the block-list paths the body was traced with (one per
+    compile) as ``jitted.block_paths``."""
+    jitted.block_paths = body.paths
+    return jitted
 
 
 def _shard(body, mesh, worker_axis, combine: bool = True):
@@ -462,6 +504,7 @@ def make_matvec_executor(
     matmul: Optional[Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]] = None,
     out_cols: Optional[int] = None,
     segmented_fn: Optional[Callable] = None,
+    stream_fn: Optional[Callable] = None,
 ) -> Callable:
     """Build the jitted USEC row-sharded step for a fixed geometry.
 
@@ -481,10 +524,15 @@ def make_matvec_executor(
     ``segmented_fn`` swaps the per-block ``fori_loop`` for the segment-aware
     whole-block-list path (a workload's ``segmented_fn(mode)`` — the Pallas
     ``usec_segmented`` kernel on TPU, one gathered flat matmul elsewhere).
+    ``stream_fn`` takes precedence for a one-column operand: the worker's
+    block list streamed from its staged tiles in one call
+    (:func:`repro.kernels.ops.usec_stream`). The returned function's
+    ``block_paths`` lists the path each compile took
+    (:func:`block_list_path`).
     """
     body = _make_worker_body(
         worker_axis, rows_total, block_rows, matmul or _default_matmul,
-        out_cols, segmented_fn,
+        out_cols, segmented_fn, stream_fn=stream_fn,
     )
     sharded = _shard(body, mesh, worker_axis)
 
@@ -493,7 +541,7 @@ def make_matvec_executor(
         return sharded(staged, blk_slot, blk_off, blk_goff, blk_include,
                        n_blocks, w)
 
-    return jax.jit(usec_step)
+    return _with_paths(jax.jit(usec_step), body)
 
 
 def make_worker_executor(
@@ -504,6 +552,7 @@ def make_worker_executor(
     matmul: Optional[Callable] = None,
     out_cols: Optional[int] = None,
     segmented_fn: Optional[Callable] = None,
+    stream_fn: Optional[Callable] = None,
 ) -> Callable:
     """Build the jitted per-worker partials for first-arrival execution.
 
@@ -528,7 +577,7 @@ def make_worker_executor(
     """
     body = _make_worker_body(
         worker_axis, rows_total, block_rows, matmul or _default_matmul,
-        out_cols, segmented_fn, combine=False,
+        out_cols, segmented_fn, combine=False, stream_fn=stream_fn,
     )
     sharded = _shard(body, mesh, worker_axis, combine=False)
 
@@ -537,7 +586,7 @@ def make_worker_executor(
         return sharded(staged, blk_slot, blk_off, blk_goff, blk_include,
                        n_blocks, w)
 
-    return jax.jit(usec_partials)
+    return _with_paths(jax.jit(usec_partials), body)
 
 
 def make_fused_executor(
@@ -550,6 +599,7 @@ def make_fused_executor(
     out_cols: Optional[int] = None,
     update: Optional[Callable] = None,
     segmented_fn: Optional[Callable] = None,
+    stream_fn: Optional[Callable] = None,
 ) -> Callable:
     """Build the jitted K-step fused window driver.
 
@@ -585,7 +635,7 @@ def make_fused_executor(
     """
     body = _make_worker_body(
         worker_axis, rows_total, block_rows, matmul or _default_matmul,
-        out_cols, segmented_fn,
+        out_cols, segmented_fn, stream_fn=stream_fn,
     )
     sharded = _shard(body, mesh, worker_axis)
     upd = update if update is not None else (lambda y, w: w)
@@ -614,4 +664,4 @@ def make_fused_executor(
         )
         return w_out, ys, ws
 
-    return jax.jit(usec_window, donate_argnums=(7, 8, 9))
+    return _with_paths(jax.jit(usec_window, donate_argnums=(7, 8, 9)), body)

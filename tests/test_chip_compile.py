@@ -191,3 +191,52 @@ def test_four_chip_executor_compiles(topo, arrival):
     fits, total = _fits(compiled)
     assert fits, total
     assert np.isfinite(total)
+
+
+BENCH_DIM = 32768    # the benchmark cells' operand width
+
+
+@pytest.mark.parametrize("n,arrival", [(1, "barrier"), (4, "first")])
+def test_streamed_step_program_compiles(topo, n, arrival):
+    """The benchmark's step programs at width 32768, with the block list
+    the runner derives on a TPU for a one-column linear workload: N=1's
+    barrier step, and first-arrival partials over J=3 cyclic tiles on the
+    2x2 mesh (b_max = 3 tiles x 512 blocks). One ``usec_stream`` kernel
+    per worker, reading the staged tiles in place: no loop over blocks
+    and no sliced copy of the tiles."""
+    from repro.api.workload import MatVecPowerIteration
+    from repro.kernels.ops import executor_matmul
+    from repro.runtime.elastic_runner import RunnerConfig, stream_block_fn
+    from repro.runtime.executor import (
+        make_matvec_executor,
+        make_worker_executor,
+    )
+
+    mesh = _worker_mesh(topo.devices[:n])
+    make = make_matvec_executor if arrival == "barrier" \
+        else make_worker_executor
+    stream = stream_block_fn(MatVecPowerIteration(),
+                             RunnerConfig(block_rows=BLOCK_ROWS), "tpu",
+                             BENCH_DIM, BENCH_DIM)
+    ex = make(mesh, "data", rows_total=BENCH_DIM, block_rows=BLOCK_ROWS,
+              matmul=executor_matmul("pallas"), stream_fn=stream)
+    t_stage, rpt = (1, BENCH_DIM) if n == 1 else (3, BENCH_DIM // 4)
+    b = t_stage * rpt // BLOCK_ROWS
+    assert b == (2048 if n == 1 else 1536)
+    by_w = NamedSharding(mesh, P("data"))
+    plan = _sds((n, b), jnp.int32, by_w)
+    compiled = ex.lower(
+        _sds((n, t_stage, rpt, BENCH_DIM), jnp.float32, by_w), plan, plan,
+        plan, _sds((n, b), jnp.float32, by_w), _sds((n,), jnp.int32, by_w),
+        _sds((BENCH_DIM,), jnp.float32, NamedSharding(mesh, P())),
+    ).compile()
+    assert ex.block_paths == ["stream"]
+    text = compiled.as_text()
+    assert "%usec_stream." in text
+    assert "%usec_matvec." not in text
+    assert not re.search(r"\swhile\(", text)
+    assert "dynamic-slice" not in text
+    _assert_named(text, "usec_step" if arrival == "barrier"
+                  else "usec_partials")
+    fits, total = _fits(compiled)
+    assert fits, total

@@ -11,6 +11,7 @@ Device tests run on forced host devices in a subprocess
 """
 
 import numpy as np
+import pytest
 
 from conftest import run_with_devices
 
@@ -261,12 +262,10 @@ print("FUSED-EWMA-OK", replans[:3], loads.round(2).tolist())
     assert "FUSED-EWMA-OK" in out
 
 
-def test_segmented_executor_paths_match_fori_loop():
-    """Engine-level segmented dispatch: the gathered flat-matmul ("ref")
-    and interpret-mode Pallas ("interpret") block-list paths reproduce the
-    per-block fori_loop executor bitwise on integer-grid data — stepwise
-    and fused, under churn with forced stragglers."""
-    out = run_with_devices(_COMMON + """
+_PATH_CASES = {
+    # The gathered flat matmul ("ref") and the interpret-mode Pallas
+    # ("interpret") segmented paths, stepwise and fused.
+    "segmented": """
 _, base = run_churn(MatVecPowerIteration(seed=0), 1, steps=6)
 for seg, K in (("ref", 1), ("ref", 4), ("interpret", 1)):
     _, res = run_churn(MatVecPowerIteration(seed=0), K, steps=6,
@@ -281,9 +280,58 @@ W = (np.round(rng.normal(size=(DIM, 4)) * 16) / 16).astype(np.float32)
 _, mm_base = run_churn(MatMat(W), 1, steps=5)
 _, mm_seg = run_churn(MatMat(W), 4, steps=5, segmented="ref")
 assert np.array_equal(mm_seg.result, mm_base.result)
-print("SEGMENTED-PARITY-OK")
-""", n_devices=4)
-    assert "SEGMENTED-PARITY-OK" in out
+""",
+    # segmented=None with Pallas kernels (interpreted here) streams a
+    # one-column block list: the stepwise barrier step, the first-arrival
+    # partials and the fused window.
+    "stream": """
+bases = {}
+for K, arrival in ((1, "barrier"), (4, "barrier"), (1, "first")):
+    if arrival not in bases:
+        eng, bases[arrival] = run_churn(MatVecPowerIteration(seed=0), 1,
+                                        steps=6, arrival=arrival)
+        assert eng.runner.block_path == "loop", arrival
+    eng, res = run_churn(MatVecPowerIteration(seed=0), K, steps=6,
+                         arrival=arrival, matmul_mode="interpret")
+    assert eng.runner.block_path == "stream", (K, arrival)
+    pi, pb = res.result, bases[arrival].result
+    assert np.array_equal(pi.eigvec, pb.eigvec), (K, arrival)
+    assert pi.residuals == pb.residuals, (K, arrival)
+    assert_report_parity(res, bases[arrival])
+    assert res.executor_cache_size == 1
+""",
+    # More than one column, or a non-linear workload: the per-block loop.
+    "loop": """
+import jax.numpy as jnp
+
+rng = np.random.default_rng(5)
+W = (np.round(rng.normal(size=(DIM, 2)) * 16) / 16).astype(np.float32)
+mr = MapReduceRows(
+    row_fn=lambda xb, w2: jnp.sum(xb.astype(jnp.float32) ** 2, axis=1,
+                                  keepdims=True),
+    reduce_fn=lambda mapped: float(mapped.sum()), out_cols=1,
+    ref_row_fn=lambda x64, w: np.sum(x64 ** 2, axis=1, keepdims=True))
+for wl in (MatMat(W), mr):
+    eng, res = run_churn(wl, 1, steps=3, matmul_mode="interpret")
+    assert eng.runner.block_path == "loop", type(wl).__name__
+    assert res.executor_cache_size == 1
+_, base = run_churn(MatMat(W), 1, steps=3)
+eng, res = run_churn(MatMat(W), 1, steps=3, matmul_mode="interpret")
+assert np.array_equal(res.result, base.result)
+""",
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PATH_CASES))
+def test_segmented_executor_paths_match_fori_loop(case):
+    """Engine-level block-list paths against the per-block fori_loop
+    executor, bitwise on integer-grid data under churn with forced
+    stragglers, the jit cache at one entry: the segmented paths, the
+    streamed one-column path (``block_path`` "stream"), and the cases
+    that keep the loop (``block_path`` "loop")."""
+    out = run_with_devices(_COMMON + _PATH_CASES[case]
+                           + "print('PATH-PARITY-OK')\n", n_devices=4)
+    assert "PATH-PARITY-OK" in out
 
 
 def test_normalize_is_correctly_rounded_on_numpy_and_jax():

@@ -142,6 +142,101 @@ def test_usec_segmented_bitwise_on_integer_grid_data():
     assert np.array_equal(got_i.astype(np.float64), loop)
 
 
+def _stream_case(name):
+    """A worker's block list over G tiles of 64 rows, K = 384, blocks of
+    16 rows: (staged, slot, off, goff, include, n_blocks, rows_total)."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    br, rpt = 16, 64
+    if name == "n1_contiguous":     # N=1: one run over the whole operand
+        g, tiles, n, b = 4, [0, 1, 2, 3], 16, 16
+        order = np.arange(16)
+    else:                           # J=3 cyclic: tiles 2, 3, 0 of 4
+        g, tiles = 4, [2, 3, 0]
+        n = {"j3_cyclic": 12, "padded": 5, "empty": 0}[name]
+        b = 12
+        order = rng.permutation(12)[:n]   # non-monotone, several slots
+    t = len(tiles)
+    staged = rng.integers(-3, 4, size=(t, rpt, 384)).astype(np.float32)
+    slot = np.zeros(b, np.int32)
+    off = np.zeros(b, np.int32)
+    goff = np.zeros(b, np.int32)
+    bpt = rpt // br
+    slot[:n] = order[:n] // bpt
+    off[:n] = order[:n] % bpt * br
+    goff[:n] = np.asarray(tiles)[slot[:n]] * rpt + off[:n]
+    inc = np.zeros(b, np.float32)
+    inc[:n] = rng.choice([0.0, 1.0], size=n)
+    if name == "n1_contiguous":
+        inc[:n] = 1.0
+    return staged, slot, off, goff, inc, np.int32(n), g * rpt
+
+
+@pytest.mark.parametrize(
+    "case", ["n1_contiguous", "j3_cyclic", "padded", "empty"])
+def test_usec_stream_interpret_matches_gather_ref_bitwise(case):
+    """The streamed block list equals the gather reference and the
+    per-block loop bit for bit on integer-grid data: products on their
+    global rows, then the include weight (an include of 0 keeps the
+    loop's -0.0), padding blocks and rows of no block left at zero."""
+    staged, slot, off, goff, inc, n, rows = _stream_case(case)
+    br = 16
+    rng = np.random.default_rng(7)
+    w = (rng.integers(-256, 257, size=(staged.shape[-1], 1))
+         / 256.0).astype(np.float32)
+    got = np.asarray(ops.usec_stream(
+        staged, slot, off, goff, inc, np.array([n]), w, rows_total=rows,
+        block_rows=br, mode="interpret"))
+    assert got.shape == (rows, 1)
+
+    compact = np.asarray(ops.segmented_gather_ref(staged, slot, off, w, br))
+    want = np.zeros((rows, 1), np.float32)
+    loop = np.zeros((rows, 1), np.float32)
+    for i in range(int(n)):
+        want[goff[i]:goff[i] + br] = compact[i] * inc[i]
+        xb = staged[slot[i], off[i]:off[i] + br].astype(np.float64)
+        loop[goff[i]:goff[i] + br] = (
+            (xb @ w.astype(np.float64)).astype(np.float32) * inc[i])
+    assert np.array_equal(got.view(np.int32), want.view(np.int32))
+    assert np.array_equal(got.view(np.int32), loop.view(np.int32))
+    if 0 < n and inc[:n].min() == 0.0:
+        assert np.signbit(got).any()   # the loop's -0.0 rows survive
+    held = np.zeros(rows, bool)
+    for i in range(int(n)):
+        held[goff[i]:goff[i] + br] = True
+    assert not got[~held].view(np.int32).any()
+
+
+@pytest.mark.parametrize("width,block_rows,rows,fits", [
+    (32768, 16, 32768, True),     # the benchmark's operand: 6.25 MiB
+    (384, 16, 256, True),
+    (65536, 16, 65536, False),    # 12.5 MiB of x, operand row and output
+    (32768, 16, 1 << 22, False),  # the output alone is 16 MiB
+    (32768, 12, 32768, False),    # not whole 8-row tiles
+    (32768, 256, 32768, False),   # a block straddles output lines
+    (32700, 16, 32768, False),    # not whole 128-lane chunks
+])
+def test_usec_stream_fits_bounds_shape_and_vmem(width, block_rows, rows,
+                                                 fits):
+    """Only shapes the streamed kernel can tile, within its VMEM budget,
+    take it; the runner keeps the loop for the rest."""
+    from repro.api.workload import MatVecPowerIteration
+    from repro.kernels.usec_segmented import (
+        STREAM_VMEM_BYTES,
+        stream_fits,
+        stream_vmem_bytes,
+    )
+    from repro.runtime.elastic_runner import RunnerConfig, stream_block_fn
+
+    assert stream_fits(width, block_rows, rows) == fits
+    if width % 128 == 0 and block_rows % 8 == 0 and 128 % block_rows == 0:
+        assert fits == (stream_vmem_bytes(width, block_rows, rows)
+                        <= STREAM_VMEM_BYTES)
+    fn = stream_block_fn(MatVecPowerIteration(),
+                         RunnerConfig(block_rows=block_rows), "tpu", width,
+                         rows)
+    assert (fn is not None) == fits
+
+
 def test_usec_segmented_auto_mode_uses_ref_off_tpu():
     rng = np.random.default_rng(3)
     staged, w, slot, off, inc = _random_block_list(rng, 2, 32, 64, 1, 4, 16)

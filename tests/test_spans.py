@@ -30,7 +30,7 @@ from jax.profiler import ProfileData
 from repro.api import ElasticEngine, EngineConfig, MatVecPowerIteration, Policy
 from repro.runtime import SyntheticSpeedClock, make_exact_matrix
 
-N, K, ARRIVAL, STEPS = {n}, {k}, {arrival!r}, {steps}
+N, K, ARRIVAL, STEPS, MODE = {n}, {k}, {arrival!r}, {steps}, {mode!r}
 BASE = [1000., 1400., 1900., 2600.][:N]
 X = make_exact_matrix(4 * 96, 0)
 
@@ -42,7 +42,7 @@ def run():
     eng = ElasticEngine(
         MatVecPowerIteration(seed=0), policy,
         EngineConfig(block_rows=16, fuse_steps=K, arrival=ARRIVAL,
-                     initial_speeds=tuple(BASE)),
+                     matmul_mode=MODE, initial_speeds=tuple(BASE)),
         backend="device", n_machines=N,
         clock=SyntheticSpeedClock(BASE, jitter_sigma=0.0, seed=0))
     res = eng.run(X, n_steps=STEPS)
@@ -96,15 +96,22 @@ def _parents(spans):
     return parent
 
 
-@pytest.mark.parametrize("n,k,arrival,program", [
-    (1, 1, "barrier", "usec_step"),
-    (1, 4, "barrier", "usec_window"),
-    (4, 1, "first", "usec_partials"),
+@pytest.mark.parametrize("n,k,arrival,program,mode,path", [
+    pytest.param(1, 1, "barrier", "usec_step", None, "loop",
+                 id="1-1-barrier-usec_step"),
+    pytest.param(1, 4, "barrier", "usec_window", None, "loop",
+                 id="1-4-barrier-usec_window"),
+    pytest.param(4, 1, "first", "usec_partials", None, "loop",
+                 id="4-1-first-usec_partials"),
+    # Pallas kernels, as on a TPU (interpreted here): the one-column
+    # block list streams.
+    pytest.param(4, 1, "first", "usec_partials", "interpret", "stream",
+                 id="4-1-first-usec_partials-stream"),
 ])
-def test_engine_spans_nest_per_step(n, k, arrival, program):
+def test_engine_spans_nest_per_step(n, k, arrival, program, mode, path):
     steps = 6
     out = json.loads(run_with_devices(
-        _RUN.format(n=n, k=k, arrival=arrival, steps=steps),
+        _RUN.format(n=n, k=k, arrival=arrival, steps=steps, mode=mode),
         n_devices=4).strip().splitlines()[-1])
     assert out["same"], "results differ with the profiler on"
     assert out["lowered"].startswith(f"module @jit_{program} ")
@@ -145,6 +152,9 @@ def test_engine_spans_nest_per_step(n, k, arrival, program):
         collapsed = [nm for j, nm in enumerate(seq)
                      if j == 0 or seq[j - 1] != nm]
         assert collapsed == phases, collapsed
+    # Each dispatch names the block-list path its program compiled.
+    enqueues = [i for i, nm in enumerate(name) if nm == "usec.enqueue"]
+    assert enqueues and all(spans[i][3]["path"] == path for i in enqueues)
     combines = [i for i, nm in enumerate(name) if nm == "usec.combine"]
     if arrival == "first":
         # One combine per step, inside that step's collect.
