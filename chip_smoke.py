@@ -12,7 +12,7 @@ exact and each result is compared bit for bit with a float64 host
 reference.
 
 One chip: power iteration stepwise (8 steps, streamed block list) and
-fused (16 steps in windows of 8, segmented kernel); an exact matmat with
+fused (16 steps in windows of 8, streamed too); an exact matmat with
 128 columns; an ``ElasticServer`` answering 16 requests. Four chips: N=4
 workers (cyclic J=3, S=1) under scripted churn, barrier and first
 arrival, each stepwise and fused.
@@ -78,8 +78,8 @@ def _kernel_in(runner) -> bool:
 
 
 def power_iteration(x, seed, kernel_mode=None):
-    """Stepwise (streamed block list) then fused (segmented kernel) power
-    iteration on one worker; the fused run's first 8 steps must equal the
+    """Stepwise then fused power iteration on one worker, both on the
+    streamed block list; the fused run's first 8 steps must equal the
     stepwise run bit for bit."""
     from repro.api import ElasticEngine, EngineConfig, MatVecPowerIteration
     from repro.api import Policy
@@ -103,11 +103,10 @@ def power_iteration(x, seed, kernel_mode=None):
     del eng, res
     gc.collect()
 
-    seg = "auto" if kernel_mode is None else kernel_mode
     eng = ElasticEngine(
         MatVecPowerIteration(seed=seed), policy,
         EngineConfig(block_rows=BLOCK_ROWS, verify="exact", fuse_steps=8,
-                     segmented=seg, matmul_mode=kernel_mode),
+                     matmul_mode=kernel_mode),
         backend="device", n_machines=1)
     res = eng.run(x, n_steps=16)
     first, steady = _times(res.reports)
@@ -234,15 +233,12 @@ def four_workers(x, seed, kernel_mode=None, steps=6):
     runs, eigvecs = {}, {}
     for arrival in ("barrier", "first"):
         for fuse in (1, 4):
-            seg = None
-            if fuse > 1:
-                seg = "auto" if kernel_mode is None else kernel_mode
             eng = ElasticEngine(
                 MatVecPowerIteration(seed=seed),
                 Policy(placement="cyclic", replication=3, stragglers=1),
                 EngineConfig(block_rows=BLOCK_ROWS, verify="exact",
-                             fuse_steps=fuse, segmented=seg,
-                             arrival=arrival, matmul_mode=kernel_mode,
+                             fuse_steps=fuse, arrival=arrival,
+                             matmul_mode=kernel_mode,
                              initial_speeds=speeds),
                 backend="device", n_machines=4,
                 clock=SyntheticSpeedClock(speeds, jitter_sigma=0.1,

@@ -64,13 +64,6 @@ class EngineConfig:
         masks are an in-graph gather, and a churn event mid-window flushes
         the window early (bitwise-equal to stepwise execution). Workloads
         whose ``fused_update`` returns None fall back to stepwise.
-      segmented: block-list execution mode. None derives it: a linear
-        workload's one-column operand streams each worker's block list
-        from its staged tiles in one ``usec_stream`` kernel where the
-        kernels run as Pallas (on TPU, or ``matmul_mode`` "pallas" /
-        "interpret"); everything else keeps the per-block loop.
-        "auto"/"pallas"/"interpret"/"ref" = the segment-aware whole-list
-        path, see :class:`~repro.runtime.elastic_runner.RunnerConfig`.
       dispatch_timeout: modeled per-dispatch deadline (seconds). A worker
         whose clocked duration exceeds it is treated as silent: masked as
         a realized straggler when the S budget covers it, demoted +
@@ -138,7 +131,6 @@ class EngineConfig:
     precompile_neighbors: bool = True
     plan_cache_size: Optional[int] = None
     fuse_steps: int = 1
-    segmented: Optional[str] = None
     # device: unannounced-failure tolerance + checkpointing
     dispatch_timeout: Optional[float] = None
     max_fault_retries: int = 3
@@ -171,8 +163,6 @@ class EngineConfig:
         _validate_choice("arrival", self.arrival, ("barrier", "first"))
         _validate_choice("replan", self.replan, ("central", "decentral"))
         _validate_choice("verify", self.verify, (None, "exact", "allclose"))
-        _validate_choice("segmented", self.segmented,
-                         (None, "auto", "pallas", "interpret", "ref"))
         _validate_choice("verify_results", self.verify_results,
                          (None, "off", "sample", "always"))
         if self.dispatch_timeout is not None and self.dispatch_timeout <= 0:
@@ -620,7 +610,6 @@ class ElasticEngine:
             precompile_neighbors=self.cfg.precompile_neighbors,
             plan_cache_size=self.cfg.plan_cache_size,
             fuse_steps=self.cfg.fuse_steps,
-            segmented=self.cfg.segmented,
             arrival=self.cfg.arrival,
             replan=self.cfg.replan,
             dispatch_timeout=self.cfg.dispatch_timeout,

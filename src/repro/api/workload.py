@@ -123,32 +123,6 @@ class Workload:
             return None
         return lambda y, w: w
 
-    def segmented_fn(
-        self, mode: Optional[str] = None, block_rows: int = 16,
-    ) -> Optional[Callable]:
-        """The whole-block-list compute of the segment-aware executor path:
-        ``f(staged, blk_slot, blk_off, blk_include, w2) -> (B, block_rows,
-        cols)`` compact per-block partials (the executor scatter-adds them
-        to global rows). None disables the path for this workload.
-
-        The default gathers every block's rows once and vmaps
-        :meth:`executor_fn` over the block axis — correct for any pure
-        ``tile_compute``. The linear workloads override this with the
-        scalar-prefetched Pallas kernel dispatch
-        (:func:`repro.kernels.ops.usec_segmented`)."""
-        import jax
-
-        from repro.kernels.usec_segmented import gather_block_rows
-
-        fn = self.executor_fn(mode)
-
-        def seg(staged, blk_slot, blk_off, blk_include, w2):
-            xg = gather_block_rows(staged, blk_slot, blk_off, block_rows)
-            part = jax.vmap(lambda xb: fn(xb, w2))(xg)
-            return part * blk_include[:, None, None]
-
-        return seg
-
     def combine(self, partials: np.ndarray):
         """Host-side combine of the fully-reduced per-row partials into the
         step result. Identity for linear workloads (the psum already summed
@@ -199,18 +173,6 @@ class Workload:
         return 1.0
 
 
-def _segmented_linear(mode: Optional[str], block_rows: int) -> Callable:
-    """The linear workloads' segmented dispatch: the scalar-prefetched
-    Pallas kernel on TPU, the gathered flat matmul elsewhere — ONE binding
-    shared by :class:`MatVec` and :class:`MatMat`."""
-    import functools
-
-    from repro.kernels.ops import usec_segmented
-
-    return functools.partial(usec_segmented, block_rows=block_rows,
-                             mode=mode)
-
-
 def _verify_linear(y, ref: np.ndarray, what: str, mode: str,
                    atol: float) -> None:
     """Shared exact/allclose check used by the linear workloads."""
@@ -248,10 +210,6 @@ class MatVec(Workload):
         from repro.kernels.ops import executor_matmul
 
         return executor_matmul(mode)
-
-    def segmented_fn(self, mode: Optional[str] = None,
-                     block_rows: int = 16) -> Optional[Callable]:
-        return _segmented_linear(mode, block_rows)
 
     def verify(self, result, operand, x64, mode, atol) -> None:
         if x64 is None:
@@ -398,10 +356,6 @@ class MatMat(Workload):
         from repro.kernels.ops import executor_matmul
 
         return executor_matmul(mode, workload="matmat")
-
-    def segmented_fn(self, mode: Optional[str] = None,
-                     block_rows: int = 16) -> Optional[Callable]:
-        return _segmented_linear(mode, block_rows)
 
     def init_operand(self, rows_total, operand=None):
         w = self.w if operand is None else np.asarray(operand, dtype=np.float32)

@@ -21,11 +21,7 @@ import jax.numpy as jnp
 from . import ref
 from .flash_attention import flash_attention_padded
 from .usec_matvec import usec_matvec_padded
-from .usec_segmented import (
-    segmented_gather_ref,
-    usec_segmented_padded,
-    usec_stream_padded,
-)
+from .usec_stream import usec_stream_padded
 
 
 def _on_tpu() -> bool:
@@ -102,63 +98,6 @@ def usec_matmat(
     return jnp.concatenate(outs, axis=1)
 
 
-def usec_segmented(
-    staged: jnp.ndarray,
-    blk_slot: jnp.ndarray,
-    blk_off: jnp.ndarray,
-    blk_include: jnp.ndarray,
-    w: jnp.ndarray,
-    block_rows: int,
-    block_k: int = 512,
-    mode: Optional[str] = None,
-) -> jnp.ndarray:
-    """A worker's whole block list in one shot: (B, block_rows, c) partials.
-
-    The segment-aware executor path: instead of B separate padded
-    :func:`usec_matvec` launches inside the per-worker loop, the full block
-    list runs as ONE ``pallas_call`` whose grid walks the scalar-prefetched
-    (slot, offset) plan indices with an fp32 accumulator over the
-    contraction dim (:mod:`repro.kernels.usec_segmented`). Include weights
-    are applied to the compact partials here (same op order as the loop:
-    matmul, then mask), and the caller scatter-adds blocks to their global
-    rows.
-
-    staged: (T, rows_per_tile, K) worker tile buffer; blk_slot/blk_off/
-    blk_include: (B,) plan arrays (offsets in rows; plans are compiled with
-    ``row_align == block_rows`` so offsets are block-aligned); w: (K, C).
-
-    mode: "pallas" | "interpret" | "ref" | None (auto: pallas on TPU, the
-    gathered flat-matmul reference elsewhere — tests pass "interpret" for
-    exact kernel semantics).
-    """
-    if mode is None:
-        mode = "pallas" if _on_tpu() else "ref"
-    if mode == "ref":
-        compact = segmented_gather_ref(staged, blk_slot, blk_off, w,
-                                       block_rows)
-    else:
-        t, rpt, k = staged.shape
-        if rpt % block_rows:
-            raise ValueError(
-                f"block_rows={block_rows} must divide rows_per_tile={rpt}")
-        # Largest 128-multiple <= block_k that divides the 128-padded K:
-        # the whole contraction dim is covered with ZERO padded columns
-        # (e.g. k=768, block_k=512 -> bk=384, not 512-with-256-pad).
-        kp = _round_up(k, 128)
-        bk = max(128, min(block_k, kp) - min(block_k, kp) % 128)
-        while kp % bk:
-            bk -= 128
-        kp = _round_up(k, bk)
-        xp = jnp.pad(staged, ((0, 0), (0, 0), (0, kp - k)))
-        wp = jnp.pad(w, ((0, kp - k), (0, 0)))
-        compact = usec_segmented_padded(
-            xp, blk_slot.astype(jnp.int32),
-            (blk_off // block_rows).astype(jnp.int32), wp,
-            block_rows=block_rows, bk=bk, interpret=(mode == "interpret"),
-        )
-    return compact * blk_include[:, None, None]
-
-
 def usec_stream(
     staged: jnp.ndarray,
     blk_slot: jnp.ndarray,
@@ -177,11 +116,11 @@ def usec_stream(
     Row ``blk_goff[i] + r`` of each block ``i < n_blocks`` holds
     ``(staged[blk_slot[i], blk_off[i] + r] . w) * blk_include[i]`` — the
     product, then the include weight, as the per-block loop computes it —
-    and every other row 0 (:mod:`repro.kernels.usec_segmented`).
+    and every other row 0 (:mod:`repro.kernels.usec_stream`).
 
     staged: (T, rows_per_tile, K); the plan rows (B,) (offsets in rows,
     block-aligned); n_blocks: (1,) the blocks in use; w: (K, 1) or (K,).
-    Needs :func:`~repro.kernels.usec_segmented.stream_fits`.
+    Needs :func:`~repro.kernels.usec_stream.stream_fits`.
 
     mode: "pallas" | "interpret".
     """

@@ -54,6 +54,7 @@ a worker's data to another device.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Callable, Optional, Sequence, Tuple
 
@@ -368,19 +369,44 @@ def worker_sharding(mesh: jax.sharding.Mesh, worker_axis: str,
     return NamedSharding(mesh, P(*([None] * lead), worker_axis))
 
 
+def stream_block_fn(linear: bool, matmul_mode: Optional[str],
+                    platform: str, width: int, rows_total: int,
+                    block_rows: int) -> Optional[Callable]:
+    """The streamed block-list kernel a step program takes for a
+    one-column operand (:func:`repro.kernels.ops.usec_stream`, bound to
+    this geometry), or None where the worker body keeps the loop.
+
+    Derived from what the runner observes: a ``linear`` workload, a row
+    width, ``block_rows`` and row count the kernel takes
+    (:func:`~repro.kernels.usec_stream.stream_fits`), and kernels that run
+    as Pallas: ``matmul_mode`` "pallas"/"interpret", or by default the
+    mesh's platform (Pallas on TPU, the jnp reference elsewhere). The
+    operand's column count is decided when the step program is traced
+    (:func:`block_list_path`).
+    """
+    from repro.kernels.ops import usec_stream
+    from repro.kernels.usec_stream import stream_fits
+
+    if not linear or not stream_fits(width, block_rows, rows_total):
+        return None
+    mode = matmul_mode or ("pallas" if platform == "tpu" else "ref")
+    if mode not in ("pallas", "interpret"):
+        return None
+    return functools.partial(usec_stream, rows_total=rows_total,
+                             block_rows=block_rows, mode=mode)
+
+
 def block_list_path(
     stream_fn: Optional[Callable],
-    segmented_fn: Optional[Callable],
     cols: int,
     out_cols: Optional[int],
 ) -> str:
     """Which block-list path a worker body compiles: ``"stream"`` (one
     ``stream_fn`` call over the whole list, for a one-column operand whose
-    output width follows it), ``"segmented"`` (``segmented_fn``) or
-    ``"loop"`` (the per-block ``fori_loop``)."""
+    output width follows it) or ``"loop"`` (the per-block ``fori_loop``)."""
     if stream_fn is not None and cols == 1 and out_cols is None:
         return "stream"
-    return "segmented" if segmented_fn is not None else "loop"
+    return "loop"
 
 
 def _make_worker_body(
@@ -389,7 +415,6 @@ def _make_worker_body(
     block_rows: int,
     mm: Callable,
     out_cols: Optional[int],
-    segmented_fn: Optional[Callable],
     combine: bool = True,
     stream_fn: Optional[Callable] = None,
 ):
@@ -402,14 +427,8 @@ def _make_worker_body(
     ``stream_fn(staged, blk_slot, blk_off, blk_goff, blk_include,
     n_blocks, w2) -> (rows_total, 1)``: the block products times their
     include weights on the blocks' global rows, 0 elsewhere — exactly the
-    loop's output (the Pallas ``usec_stream`` kernel).
-
-    With ``segmented_fn`` the per-block ``fori_loop`` is replaced by one
-    whole-block-list call (the segment-aware kernel path): ``segmented_fn``
-    returns the (B, block_rows, cols) compact partials, which are
-    scatter-added into the output rows. Per-worker output rows are disjoint
-    (each worker computes an assigned row once), so add equals the loop's
-    overwrite; padding blocks carry include == 0 and add exact zeros.
+    loop's output (the Pallas ``usec_stream`` kernel). Otherwise a
+    ``fori_loop`` runs ``mm`` on each block.
 
     ``combine`` psums the partials over the worker axis (the barrier
     master); without it each worker returns its own partial with a leading
@@ -424,23 +443,13 @@ def _make_worker_body(
         blk_goff, blk_include = blk_goff[0], blk_include[0]
         w2 = w if w.ndim == 2 else w[:, None]
         cols = w2.shape[1] if out_cols is None else out_cols
-        path = block_list_path(stream_fn, segmented_fn, w2.shape[1],
-                               out_cols)
+        path = block_list_path(stream_fn, w2.shape[1], out_cols)
         body.paths.append(path)
 
-        if path != "loop":
+        if path == "stream":
             def _compute():
-                if path == "stream":
-                    return stream_fn(staged, blk_slot, blk_off, blk_goff,
-                                     blk_include, n_blocks, w2)
-                compact = segmented_fn(staged, blk_slot, blk_off,
-                                       blk_include, w2)
-                rows = (
-                    blk_goff[:, None]
-                    + jnp.arange(block_rows, dtype=jnp.int32)
-                ).reshape(-1)
-                return jnp.zeros((rows_total, cols), jnp.float32) \
-                    .at[rows].add(compact.reshape(-1, cols))
+                return stream_fn(staged, blk_slot, blk_off, blk_goff,
+                                 blk_include, n_blocks, w2)
 
             # Zero-trip workers (preempted machines; inactive padding steps
             # of a fused window, whose trip counts are zeroed in-graph)
@@ -503,7 +512,6 @@ def make_matvec_executor(
     block_rows: int,
     matmul: Optional[Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]] = None,
     out_cols: Optional[int] = None,
-    segmented_fn: Optional[Callable] = None,
     stream_fn: Optional[Callable] = None,
 ) -> Callable:
     """Build the jitted USEC row-sharded step for a fixed geometry.
@@ -521,18 +529,16 @@ def make_matvec_executor(
     output width when it differs from the operand's column count (the
     map-reduce workloads of :mod:`repro.api`).
 
-    ``segmented_fn`` swaps the per-block ``fori_loop`` for the segment-aware
-    whole-block-list path (a workload's ``segmented_fn(mode)`` — the Pallas
-    ``usec_segmented`` kernel on TPU, one gathered flat matmul elsewhere).
-    ``stream_fn`` takes precedence for a one-column operand: the worker's
-    block list streamed from its staged tiles in one call
+    ``stream_fn`` (:func:`stream_block_fn`) swaps the per-block
+    ``fori_loop`` for a one-column operand: the worker's block list
+    streamed from its staged tiles in one call
     (:func:`repro.kernels.ops.usec_stream`). The returned function's
     ``block_paths`` lists the path each compile took
     (:func:`block_list_path`).
     """
     body = _make_worker_body(
         worker_axis, rows_total, block_rows, matmul or _default_matmul,
-        out_cols, segmented_fn, stream_fn=stream_fn,
+        out_cols, stream_fn=stream_fn,
     )
     sharded = _shard(body, mesh, worker_axis)
 
@@ -551,7 +557,6 @@ def make_worker_executor(
     block_rows: int,
     matmul: Optional[Callable] = None,
     out_cols: Optional[int] = None,
-    segmented_fn: Optional[Callable] = None,
     stream_fn: Optional[Callable] = None,
 ) -> Callable:
     """Build the jitted per-worker partials for first-arrival execution.
@@ -577,7 +582,7 @@ def make_worker_executor(
     """
     body = _make_worker_body(
         worker_axis, rows_total, block_rows, matmul or _default_matmul,
-        out_cols, segmented_fn, combine=False, stream_fn=stream_fn,
+        out_cols, combine=False, stream_fn=stream_fn,
     )
     sharded = _shard(body, mesh, worker_axis, combine=False)
 
@@ -598,7 +603,6 @@ def make_fused_executor(
     matmul: Optional[Callable] = None,
     out_cols: Optional[int] = None,
     update: Optional[Callable] = None,
-    segmented_fn: Optional[Callable] = None,
     stream_fn: Optional[Callable] = None,
 ) -> Callable:
     """Build the jitted K-step fused window driver.
@@ -635,7 +639,7 @@ def make_fused_executor(
     """
     body = _make_worker_body(
         worker_axis, rows_total, block_rows, matmul or _default_matmul,
-        out_cols, segmented_fn, stream_fn=stream_fn,
+        out_cols, stream_fn=stream_fn,
     )
     sharded = _shard(body, mesh, worker_axis)
     upd = update if update is not None else (lambda y, w: w)

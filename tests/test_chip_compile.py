@@ -61,21 +61,34 @@ def test_usec_matvec_kernel_compiles(topo, cols):
     assert "%usec_matvec." in text  # the kernel's name in device traces
 
 
-@pytest.mark.parametrize("cols", [1, 8, 128])
-def test_usec_segmented_kernel_compiles(topo, cols):
-    from repro.kernels.usec_segmented import usec_segmented_padded
+@pytest.mark.parametrize("tiles,rows_per_tile,width,rows_total", [
+    pytest.param(1, DIM, DIM, DIM, id="n1-contiguous"),
+    pytest.param(3, DIM // 4, DIM, DIM, id="j3-cyclic-bmax"),
+    pytest.param(4, 64, 384, 256, id="w384-rows256"),
+])
+def test_usec_stream_kernel_compiles(topo, tiles, rows_per_tile, width,
+                                     rows_total):
+    """A worker's block list over all its staged tiles (a J=3 worker's is
+    padded to b_max) through the streamed kernel: a Mosaic call that reads
+    each block in place, with no XLA loop over blocks and no sliced copy
+    of the tiles."""
+    from repro.kernels.usec_stream import usec_stream_padded
 
     one = SingleDeviceSharding(topo.devices[0])
-    b = DIM // BLOCK_ROWS
-    compiled = usec_segmented_padded.lower(
-        _sds((1, DIM, DIM), jnp.float32, one),
-        _sds((b,), jnp.int32, one), _sds((b,), jnp.int32, one),
-        _sds((DIM, cols), jnp.float32, one),
-        block_rows=BLOCK_ROWS, bk=512,
+    blocks = tiles * rows_per_tile // BLOCK_ROWS
+    plan = _sds((blocks,), jnp.int32, one)
+    compiled = usec_stream_padded.lower(
+        _sds((tiles, rows_per_tile, width), jnp.float32, one),
+        _sds((1,), jnp.int32, one), plan, plan, plan,
+        _sds((blocks,), jnp.float32, one),
+        _sds((1, width), jnp.float32, one),
+        rows_total=rows_total, block_rows=BLOCK_ROWS,
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    assert "%usec_segmented." in text
+    assert "%usec_stream." in text
+    assert not re.search(r"\swhile\(", text)
+    assert "dynamic-slice" not in text
 
 
 def _worker_mesh(devices):
@@ -131,7 +144,7 @@ def test_one_chip_stepwise_executor_compiles(topo):
 def test_one_chip_fused_executor_compiles(topo):
     from repro.api.workload import MatVecPowerIteration
     from repro.kernels.ops import executor_matmul
-    from repro.runtime.executor import make_fused_executor
+    from repro.runtime.executor import make_fused_executor, stream_block_fn
 
     mesh = _worker_mesh(topo.devices[:1])
     wl = MatVecPowerIteration()
@@ -139,7 +152,8 @@ def test_one_chip_fused_executor_compiles(topo):
     ex = make_fused_executor(
         mesh, "data", rows_total=DIM, block_rows=BLOCK_ROWS, fuse_steps=k,
         matmul=executor_matmul("pallas"), update=wl.fused_update(),
-        segmented_fn=wl.segmented_fn("pallas", block_rows=BLOCK_ROWS))
+        stream_fn=stream_block_fn(wl.linear, "pallas", "tpu", DIM, DIM,
+                                  BLOCK_ROWS))
     by_kw = NamedSharding(mesh, P(None, "data"))
     rep = NamedSharding(mesh, P())
     staged = _step_args(mesh, 1, 1, b)[0]
@@ -153,7 +167,7 @@ def test_one_chip_fused_executor_compiles(topo):
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    assert "%usec_segmented." in text
+    assert "%usec_stream." in text
     _assert_named(text, "usec_window")
     fits, total = _fits(compiled)
     assert fits, total
@@ -206,18 +220,17 @@ def test_streamed_step_program_compiles(topo, n, arrival):
     and no sliced copy of the tiles."""
     from repro.api.workload import MatVecPowerIteration
     from repro.kernels.ops import executor_matmul
-    from repro.runtime.elastic_runner import RunnerConfig, stream_block_fn
     from repro.runtime.executor import (
         make_matvec_executor,
         make_worker_executor,
+        stream_block_fn,
     )
 
     mesh = _worker_mesh(topo.devices[:n])
     make = make_matvec_executor if arrival == "barrier" \
         else make_worker_executor
-    stream = stream_block_fn(MatVecPowerIteration(),
-                             RunnerConfig(block_rows=BLOCK_ROWS), "tpu",
-                             BENCH_DIM, BENCH_DIM)
+    stream = stream_block_fn(MatVecPowerIteration().linear, None, "tpu",
+                             BENCH_DIM, BENCH_DIM, BLOCK_ROWS)
     ex = make(mesh, "data", rows_total=BENCH_DIM, block_rows=BLOCK_ROWS,
               matmul=executor_matmul("pallas"), stream_fn=stream)
     t_stage, rpt = (1, BENCH_DIM) if n == 1 else (3, BENCH_DIM // 4)

@@ -263,27 +263,20 @@ print("FUSED-EWMA-OK", replans[:3], loads.round(2).tolist())
 
 
 _PATH_CASES = {
-    # The gathered flat matmul ("ref") and the interpret-mode Pallas
-    # ("interpret") segmented paths, stepwise and fused.
-    "segmented": """
-_, base = run_churn(MatVecPowerIteration(seed=0), 1, steps=6)
-for seg, K in (("ref", 1), ("ref", 4), ("interpret", 1)):
-    _, res = run_churn(MatVecPowerIteration(seed=0), K, steps=6,
-                       segmented=seg)
-    pi, pb = res.result, base.result
-    assert np.array_equal(pi.eigvec, pb.eigvec), (seg, K)
-    assert pi.residuals == pb.residuals, (seg, K)
-    assert res.executor_cache_size == 1
-
+    # Four columns keep the per-block loop; a fused window of them equals
+    # the stepwise run.
+    "matmat-fused": """
 rng = np.random.default_rng(5)
 W = (np.round(rng.normal(size=(DIM, 4)) * 16) / 16).astype(np.float32)
 _, mm_base = run_churn(MatMat(W), 1, steps=5)
-_, mm_seg = run_churn(MatMat(W), 4, steps=5, segmented="ref")
-assert np.array_equal(mm_seg.result, mm_base.result)
+eng, mm_fused = run_churn(MatMat(W), 4, steps=5)
+assert eng.runner.block_path == "loop"
+assert np.array_equal(mm_fused.result, mm_base.result)
+assert mm_fused.executor_cache_size == 1
 """,
-    # segmented=None with Pallas kernels (interpreted here) streams a
-    # one-column block list: the stepwise barrier step, the first-arrival
-    # partials and the fused window.
+    # Pallas kernels (interpreted here) stream a one-column block list:
+    # the stepwise barrier step, the first-arrival partials and the fused
+    # window.
     "stream": """
 bases = {}
 for K, arrival in ((1, "barrier"), (4, "barrier"), (1, "first")):
@@ -323,12 +316,12 @@ assert np.array_equal(res.result, base.result)
 
 
 @pytest.mark.parametrize("case", sorted(_PATH_CASES))
-def test_segmented_executor_paths_match_fori_loop(case):
+def test_block_list_paths_match_fori_loop(case):
     """Engine-level block-list paths against the per-block fori_loop
     executor, bitwise on integer-grid data under churn with forced
-    stragglers, the jit cache at one entry: the segmented paths, the
-    streamed one-column path (``block_path`` "stream"), and the cases
-    that keep the loop (``block_path`` "loop")."""
+    stragglers, the jit cache at one entry: the streamed one-column path
+    (``block_path`` "stream"), and the cases that keep the loop
+    (``block_path`` "loop"), stepwise and fused."""
     out = run_with_devices(_COMMON + _PATH_CASES[case]
                            + "print('PATH-PARITY-OK')\n", n_devices=4)
     assert "PATH-PARITY-OK" in out

@@ -80,68 +80,8 @@ def test_matvec_auto_mode_dispatches_to_ref_on_cpu():
 
 
 # ---------------------------------------------------------------------- #
-# Segment-aware kernel: one pallas_call over a worker's whole block list
+# Streamed block list: one pallas_call over a worker's whole block list
 # ---------------------------------------------------------------------- #
-def _random_block_list(rng, t, rpt, k, c, b, block_rows):
-    staged = rng.normal(size=(t, rpt, k)).astype(np.float32)
-    w = rng.normal(size=(k, c)).astype(np.float32)
-    slot = rng.integers(0, t, size=b).astype(np.int32)
-    off = (rng.integers(0, rpt // block_rows, size=b)
-           * block_rows).astype(np.int32)
-    inc = rng.choice([0.0, 1.0], size=b).astype(np.float32)
-    return staged, w, slot, off, inc
-
-
-@pytest.mark.parametrize("t,rpt,k,c,b", [
-    (3, 64, 256, 1, 7),
-    (2, 32, 100, 3, 5),     # contraction-dim padding path
-    (4, 96, 768, 8, 12),
-])
-def test_usec_segmented_interpret_matches_gather_ref(t, rpt, k, c, b):
-    """Interpret-mode kernel semantics vs the jnp gather reference."""
-    block_rows = 16
-    rng = np.random.default_rng(t * 100 + k)
-    staged, w, slot, off, inc = _random_block_list(
-        rng, t, rpt, k, c, b, block_rows)
-    got = ops.usec_segmented(staged, slot, off, inc, w,
-                             block_rows=block_rows, mode="interpret")
-    want = ops.usec_segmented(staged, slot, off, inc, w,
-                              block_rows=block_rows, mode="ref")
-    assert got.shape == (b, block_rows, c)
-    # fp32 K-tiled accumulation vs one flat dot: ~1e-4 relative on normal
-    # data (bitwise equality is asserted separately on integer-grid data).
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_usec_segmented_bitwise_on_integer_grid_data():
-    """On integer-valued operands every partial sum is exactly
-    representable, so the kernel's K-tiled accumulator, the gather
-    reference and a per-block loop all agree BITWISE — the property the
-    elastic runner's exact-verify mode relies on."""
-    block_rows = 16
-    rng = np.random.default_rng(0)
-    t, rpt, k, c, b = 3, 64, 640, 2, 9
-    staged = rng.integers(-3, 4, size=(t, rpt, k)).astype(np.float32)
-    w = (rng.integers(-8, 9, size=(k, c)) / 16.0).astype(np.float32)
-    slot = rng.integers(0, t, size=b).astype(np.int32)
-    off = (rng.integers(0, rpt // block_rows, size=b)
-           * block_rows).astype(np.int32)
-    inc = rng.choice([0.0, 1.0], size=b).astype(np.float32)
-    got_i = np.asarray(ops.usec_segmented(
-        staged, slot, off, inc, w, block_rows=block_rows, block_k=256,
-        mode="interpret"))
-    got_r = np.asarray(ops.usec_segmented(
-        staged, slot, off, inc, w, block_rows=block_rows, mode="ref"))
-    loop = np.stack([
-        (staged[slot[i], off[i]: off[i] + block_rows].astype(np.float64)
-         @ w.astype(np.float64)) * inc[i]
-        for i in range(b)
-    ])
-    assert np.array_equal(got_i, got_r)
-    assert np.array_equal(got_i.astype(np.float64), loop)
-
-
 def _stream_case(name):
     """A worker's block list over G tiles of 64 rows, K = 384, blocks of
     16 rows: (staged, slot, off, goff, include, n_blocks, rows_total)."""
@@ -178,6 +118,8 @@ def test_usec_stream_interpret_matches_gather_ref_bitwise(case):
     per-block loop bit for bit on integer-grid data: products on their
     global rows, then the include weight (an include of 0 keeps the
     loop's -0.0), padding blocks and rows of no block left at zero."""
+    from repro.kernels.usec_stream import block_list_ref
+
     staged, slot, off, goff, inc, n, rows = _stream_case(case)
     br = 16
     rng = np.random.default_rng(7)
@@ -188,7 +130,7 @@ def test_usec_stream_interpret_matches_gather_ref_bitwise(case):
         block_rows=br, mode="interpret"))
     assert got.shape == (rows, 1)
 
-    compact = np.asarray(ops.segmented_gather_ref(staged, slot, off, w, br))
+    compact = np.asarray(block_list_ref(staged, slot, off, w, br))
     want = np.zeros((rows, 1), np.float32)
     loop = np.zeros((rows, 1), np.float32)
     for i in range(int(n)):
@@ -220,27 +162,51 @@ def test_usec_stream_fits_bounds_shape_and_vmem(width, block_rows, rows,
     """Only shapes the streamed kernel can tile, within its VMEM budget,
     take it; the runner keeps the loop for the rest."""
     from repro.api.workload import MatVecPowerIteration
-    from repro.kernels.usec_segmented import (
+    from repro.kernels.usec_stream import (
         STREAM_VMEM_BYTES,
         stream_fits,
         stream_vmem_bytes,
     )
-    from repro.runtime.elastic_runner import RunnerConfig, stream_block_fn
+    from repro.runtime.executor import stream_block_fn
 
     assert stream_fits(width, block_rows, rows) == fits
     if width % 128 == 0 and block_rows % 8 == 0 and 128 % block_rows == 0:
         assert fits == (stream_vmem_bytes(width, block_rows, rows)
                         <= STREAM_VMEM_BYTES)
-    fn = stream_block_fn(MatVecPowerIteration(),
-                         RunnerConfig(block_rows=block_rows), "tpu", width,
-                         rows)
+    fn = stream_block_fn(MatVecPowerIteration().linear, None, "tpu", width,
+                         rows, block_rows)
     assert (fn is not None) == fits
 
 
-def test_usec_segmented_auto_mode_uses_ref_off_tpu():
-    rng = np.random.default_rng(3)
-    staged, w, slot, off, inc = _random_block_list(rng, 2, 32, 64, 1, 4, 16)
-    auto = ops.usec_segmented(staged, slot, off, inc, w, block_rows=16)
-    want = ops.usec_segmented(staged, slot, off, inc, w, block_rows=16,
-                              mode="ref")
-    assert np.array_equal(np.asarray(auto), np.asarray(want))
+def _map_rows():
+    from repro.api.workload import MapReduceRows
+
+    return MapReduceRows(
+        row_fn=lambda xb, w2: jnp.sum(xb, axis=1, keepdims=True),
+        reduce_fn=lambda mapped: float(mapped.sum()), out_cols=1)
+
+
+@pytest.mark.parametrize("workload,platform,mode,cols,path,kernel_mode", [
+    ("powit", "tpu", None, 1, "stream", "pallas"),
+    ("powit", "tpu", "pallas", 1, "stream", "pallas"),
+    ("powit", "cpu", None, 1, "loop", None),
+    ("powit", "cpu", "interpret", 1, "stream", "interpret"),
+    ("powit", "tpu", "ref", 1, "loop", None),
+    ("maprows", "tpu", None, 1, "loop", None),
+    ("matmat", "tpu", None, 4, "loop", "pallas"),
+])
+def test_block_list_path_decision(workload, platform, mode, cols, path,
+                                  kernel_mode):
+    """The executor's one block-list decision, from what the runner
+    observes: a linear workload whose kernels run as Pallas (TPU by
+    default, or an explicit "pallas"/"interpret") streams a one-column
+    operand; the CPU default, "ref", a non-linear workload and an operand
+    of several columns (decided at trace time) keep the per-block loop."""
+    from repro.api.workload import MatMat, MatVecPowerIteration
+    from repro.runtime.executor import block_list_path, stream_block_fn
+
+    wl = {"powit": MatVecPowerIteration, "maprows": _map_rows,
+          "matmat": MatMat}[workload]()
+    fn = stream_block_fn(wl.linear, mode, platform, 384, 256, 16)
+    assert (None if fn is None else fn.keywords["mode"]) == kernel_mode
+    assert block_list_path(fn, cols, wl.out_cols) == path

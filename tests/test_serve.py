@@ -163,11 +163,6 @@ def test_engine_config_rejects_bad_verify():
         EngineConfig(verify="bitwise")
 
 
-def test_engine_config_rejects_bad_segmented():
-    with pytest.raises(ValueError, match=r"segmented.*pallas.*'fast'"):
-        EngineConfig(segmented="fast")
-
-
 def test_runner_config_rejects_bad_arrival():
     with pytest.raises(ValueError, match=r"arrival.*first.*'last'"):
         RunnerConfig(arrival="last")
@@ -181,11 +176,6 @@ def test_runner_config_rejects_bad_replan():
 def test_runner_config_rejects_bad_verify():
     with pytest.raises(ValueError, match=r"verify.*allclose.*'yes'"):
         RunnerConfig(verify="yes")
-
-
-def test_runner_config_rejects_bad_segmented():
-    with pytest.raises(ValueError, match=r"segmented.*interpret.*'gpu'"):
-        RunnerConfig(segmented="gpu")
 
 
 def test_policy_rejects_bad_placement():
